@@ -50,9 +50,7 @@ def main():
     rng = np.random.default_rng(0)
     z = jnp.asarray(rng.normal(size=(32, 8, 4, 16)).astype(np.float32))
 
-    from repro import compat
-
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn = jax.jit(lambda zz: lp_forward_shard_map(denoise, zz, plan, 0,
                                                      mesh, "data"))
         compiled = fn.lower(z).compile()
@@ -72,7 +70,7 @@ def main():
     # axis, TP Phi_m as a black box, eager ppermute issue (PR 3)
     from repro.core.hybrid import lp_forward_halo_hybrid
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn_h = jax.jit(lambda zz: lp_forward_halo_hybrid(
             denoise, zz, plan, 0, mesh, "data", "model", codec="int8"))
         compiled_h = fn_h.lower(z).compile()

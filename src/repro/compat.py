@@ -1,74 +1,25 @@
-"""JAX version compatibility shims.
+"""Thin helpers over the JAX surface (jax 0.9).
 
-The codebase is written against the modern JAX surface (``jax.shard_map``,
-``jax.set_mesh``, ``jax.make_mesh(axis_types=...)``, dict-returning
-``Compiled.cost_analysis``).  Older jaxlibs (0.4.x) spell these differently;
-everything version-sensitive is funneled through here so call sites stay on
-the new names.
+Every mesh is built with Auto axis types (``jax.make_mesh`` would
+otherwise make them Explicit, which the LP engines do not annotate for),
+and ``cost_analysis`` never returns None.
 """
 from __future__ import annotations
 
-import contextlib
-from typing import Any, Dict, Optional, Sequence, Tuple
+from typing import Any, Dict, Sequence
 
 import jax
-
-try:  # jax >= 0.5
-    from jax.sharding import AxisType  # type: ignore
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """``jax.make_mesh`` with Auto axis types where supported."""
-    if AxisType is not None:
-        try:
-            return jax.make_mesh(
-                tuple(shape), tuple(axes),
-                axis_types=(AxisType.Auto,) * len(tuple(axes)),
-            )
-        except TypeError:
-            pass
-    return jax.make_mesh(tuple(shape), tuple(axes))
-
-
-def set_mesh(mesh):
-    """Context manager activating ``mesh`` for bare-PartitionSpec lowering.
-
-    ``jax.set_mesh`` on new JAX; on 0.4.x the Mesh object itself is the
-    context manager that installs the resource environment.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` (new) or ``jax.experimental.shard_map`` (0.4.x).
-
-    ``check_vma`` maps onto the old ``check_rep`` flag — both toggle the
-    replication/varying-manual-axes checker.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
+    """``jax.make_mesh`` with Auto axis types."""
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def cost_analysis(compiled) -> Dict[str, Any]:
-    """Normalize ``Compiled.cost_analysis()`` to a flat dict.
-
-    Old jaxlibs return a one-element list of per-device dicts; new ones
-    return the dict directly (or None when the backend has no analysis).
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
+    """``Compiled.cost_analysis()`` as a dict ({} when the backend has
+    no analysis)."""
+    return compiled.cost_analysis() or {}

@@ -26,7 +26,7 @@ import dataclasses
 import time
 from collections import OrderedDict
 from contextlib import nullcontext
-from typing import Any, Callable, Optional, Sequence, Tuple
+from typing import Any, Callable, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -479,7 +479,7 @@ def lp_denoise(
     patch_sizes: Sequence[int],
     spatial_axes: Sequence[int],
     uniform: bool = False,
-    extras: Tuple = (),
+    extras: Union[Tuple, Callable[[], Tuple]] = (),
     compiler: Optional[LPStepCompiler] = None,
     fuse_scan: bool = True,
     step_hook: Optional[Callable[[int], None]] = None,
@@ -491,7 +491,10 @@ def lp_denoise(
     """Full T-step LP denoising on the compiled fast path.
 
     ``denoise_fn(window, t, *extras)`` takes the timestep (and any
-    conditioning in ``extras``) as traced arguments; ``sampler`` provides
+    conditioning in ``extras``) as traced arguments.  ``extras`` may also
+    be a zero-argument callable returning that tuple, read at every
+    dispatch: a ``step_hook`` that moves the mesh can then hand the next
+    step arguments placed on the new one.  ``sampler`` provides
     ``timestep(i)`` / ``step_scalars(i)`` / ``update(z, pred, scalars)``
     (see ``diffusion/sampler.py``).  Pass a prebuilt ``compiler`` to reuse
     compiled steps across calls (the serving engine does, across batches);
@@ -545,6 +548,7 @@ def lp_denoise(
     """
     if step_hook is not None:
         fuse_scan = False
+    get_extras = extras if callable(extras) else (lambda: extras)
     comp = compiler
     if comp is None:
         if denoise_fn is None:
@@ -642,6 +646,7 @@ def lp_denoise(
                                          epoch=comp.plan_epoch))
             t0 = time.perf_counter()
             with span:
+                extras = get_extras()
                 if len(idxs) == 1:
                     fn = comp.step_fn(dim, z, 1, scs[0], extras,
                                       codec=seg_codec)
@@ -722,6 +727,7 @@ def lp_denoise(
                                      codec=ck_name, epoch=comp.plan_epoch))
         t0 = time.perf_counter()
         with span:
+            extras = get_extras()
             fn = comp.step_fn(dim, z, 1, sc, extras, codec=seg_codec)
             if stateful:
                 z, cur_state = fn(z, cur_state, t, sc, extras)

@@ -31,8 +31,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 from .uniform import UniformPlan
 
 DenoiseFn = Callable[[jnp.ndarray], jnp.ndarray]
@@ -68,7 +66,8 @@ def blend_windows(
     (``kernels/latent_blend``) on TPU — one pass over the output instead
     of the K+2 latent-sized HBM round trips of the jnp scatter-add below.
     Off-TPU the kernel only runs in (slow, Python) interpret mode, so it
-    stays opt-in there (tests force it on small shapes).
+    stays opt-in there (tests force it on small shapes); ``kernels.ops``
+    decides compiled vs interpreted from the backend.
     """
     K = plan.num_partitions
     if use_kernel is None:
@@ -76,7 +75,6 @@ def blend_windows(
     if use_kernel:
         from repro.kernels import ops
 
-        interpret = jax.default_backend() != "tpu"
         p = jnp.moveaxis(preds, axis + 1, 1)        # (K, W, rest...)
         rest = p.shape[2:]
         flat = int(np.prod(rest)) if rest else 1
@@ -85,7 +83,6 @@ def blend_windows(
             jnp.asarray(window_weights(plan)),
             jnp.asarray(plan.normalizer()),
             plan.starts, plan.window, plan.extent,
-            interpret=interpret,
         )
         return jnp.moveaxis(out.reshape((plan.extent,) + rest), 0, axis)
     w = jnp.asarray(window_weights(plan))  # (K, window)
@@ -131,14 +128,13 @@ def blend_windows_coded(
     if codec.name == "int8" and use_kernel:
         from repro.kernels import ops
 
-        interpret = jax.default_backend() != "tpu"
         p = jnp.moveaxis(preds, axis + 1, 1)         # (K, W, rest...)
         rest = p.shape[2:]
         flat = int(np.prod(rest)) if rest else 1
         p = p.reshape(K, plan.window, flat)
         wires, scales = [], []
         for k in range(K):
-            wire, scale = ops.int8_quantize(p[k], interpret=interpret)
+            wire, scale = ops.int8_quantize(p[k])
             wires.append(wire)
             scales.append(scale[0, 0])
         out = ops.dequant_blend(
@@ -146,7 +142,7 @@ def blend_windows_coded(
             jnp.asarray(window_weights(plan)),
             jnp.asarray(plan.normalizer()),
             plan.starts, plan.window, plan.extent,
-            interpret=interpret, out_dtype=preds.dtype,
+            out_dtype=preds.dtype,
         )
         return jnp.moveaxis(out.reshape((plan.extent,) + rest), 0, axis)
     # vmapped over the stacked axis (one per-slab scale per window): under
@@ -198,13 +194,6 @@ def lp_forward_gspmd(
     family, not GSPMD, is the production codec path; see
     ``comm_model.comm_lp_gspmd_codec``.  Stateless codecs only (residual
     state needs the explicit halo schedule).
-
-    Caveat (jax 0.4.x): the legacy partitioner lowers the stacked-axis
-    reduce to an all-reduce over EVERY device when the mesh has additional
-    (replicated) axes, multiplying the result by their product — execute
-    this engine on a single-axis mesh there (compile-only analysis, e.g.
-    the dry-run, is unaffected by values).  Meshes with Auto axis types
-    (jax >= 0.5) lower it correctly.
     """
     if codec is not None:
         from repro.comm.codecs import get_codec
@@ -282,7 +271,7 @@ def lp_forward_shard_map(
 
     # Replicated in/out along every axis; the denoiser may use other axes
     # (e.g. tensor parallelism over "model") internally.
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         per_device,
         mesh=mesh,
         in_specs=P(),
@@ -473,7 +462,7 @@ def lp_forward_halo(
             )
             return _reassemble(_core_gather_raw(core), z_rep.dtype)
 
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             per_device,
             mesh=mesh,
             in_specs=P(),
@@ -505,7 +494,7 @@ def lp_forward_halo(
                                                  nan_guard=nan_guard)
             return _reassemble(gathered, z_rep.dtype)
 
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             per_device_codec,
             mesh=mesh,
             in_specs=P(),
@@ -532,7 +521,7 @@ def lp_forward_halo(
         out = _reassemble(gathered, z_rep.dtype)
         return out, jax.tree.map(lambda s: s[None], st)
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         per_device_stateful,
         mesh=mesh,
         in_specs=(P(), P(lp_axis)),

@@ -36,18 +36,21 @@ def make_guided_denoiser(dit_forward, params, cfg_model, context, null_context,
     return guided
 
 
-def make_guided_step_denoiser(dit_forward, params, cfg_model,
+def make_guided_step_denoiser(dit_forward, cfg_model,
                               guidance_default: float = 5.0):
     """Fully-traced guided denoiser for the compiled LP step cache.
 
-    Unlike :func:`make_guided_denoiser`, the conditioning is NOT closed
-    over: ``(window, t, context, null_context, guidance)`` are all traced
-    arguments, so one compiled step serves every batch of the same
-    geometry — the serving engine builds this once per engine, not once
-    per batch.  ``t`` is a traced f32 scalar (the LP step protocol).
+    Unlike :func:`make_guided_denoiser`, neither the parameters nor the
+    conditioning are closed over: ``(window, t, params, context,
+    null_context, guidance)`` are all traced arguments, so one compiled
+    step serves every batch of the same geometry — the serving engine
+    builds this once per engine, not once per batch.  Parameters that a
+    jitted closure captured would be embedded in the program as
+    constants (4.49 GB at WAN2.1-1.3B's published width).  ``t`` is a
+    traced f32 scalar (the LP step protocol).
     """
 
-    def guided(window, t, context, null_context, guidance=None):
+    def guided(window, t, params, context, null_context, guidance=None):
         g = guidance_default if guidance is None else guidance
         b = window.shape[0]
         z2 = jnp.concatenate([window, window], axis=0)

@@ -108,7 +108,7 @@ def flash_attention(
     window: int = 0,
     blk_q: int = 128,
     blk_k: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
     skip_upper: bool = False,
 ) -> jnp.ndarray:
     B, Sq, H, D = q.shape

@@ -35,7 +35,7 @@ def guidance_update(
     w: float,
     dt: float,
     blk: int = 65536,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     shape = z.shape
     flat = z.size

@@ -14,11 +14,13 @@ Layout: preds (K, W, F) where the partition dim is dim 1 and F flattens
 every other latent dim.  Grid (F_blocks, K) — K innermost so the output
 tile accumulates across partitions in VMEM scratch:
 
-    preds block (1, W, bf)      weights row (1, W)
+    preds block (1, W, bf)      weights (K, W), whole, row k read in-kernel
     out block   (E, bf)         acc scratch (E, bf) f32
 
-Starts are static (partition geometry is compile-time), so the scatter
-offset per k is a constant-indexed dynamic slice.
+The weights go in whole because a (1, W) block over the (K, W) array has
+a second-to-last block dim of 1, which the TPU lowering refuses.  Starts
+are static (partition geometry is compile-time), so the scatter offset
+per k is a constant-indexed slice.
 """
 from __future__ import annotations
 
@@ -40,12 +42,12 @@ def _kernel(preds_ref, w_ref, norm_ref, o_ref, acc_ref, *,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     pred = preds_ref[0].astype(jnp.float32)          # (W, bf)
-    w = w_ref[0, :]                                   # (W,)
+    w = w_ref[ikk, :]                                 # (W,)
     contrib = pred * w[:, None]
+
     # static scatter offset per partition index
     def add_at(s):
-        cur = pl.load(acc_ref, (pl.ds(s, window), slice(None)))
-        pl.store(acc_ref, (pl.ds(s, window), slice(None)), cur + contrib)
+        acc_ref[pl.ds(s, window), :] += contrib
 
     branches = [functools.partial(add_at, s) for s in starts]
     jax.lax.switch(ikk, branches)
@@ -68,7 +70,7 @@ def latent_blend(
     window: int,
     extent: int,
     blk_f: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     K, W, F = preds.shape
     assert W == window and len(starts) == K
@@ -85,7 +87,7 @@ def latent_blend(
         grid=(nf, K),
         in_specs=[
             pl.BlockSpec((1, window, blk_f), lambda jf, kk: (kk, 0, jf)),
-            pl.BlockSpec((1, window), lambda jf, kk: (kk, 0)),
+            pl.BlockSpec((K, window), lambda jf, kk: (0, 0)),
             pl.BlockSpec((1, extent), lambda jf, kk: (0, 0)),
         ],
         out_specs=pl.BlockSpec((extent, blk_f), lambda jf, kk: (0, jf)),

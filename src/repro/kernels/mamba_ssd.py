@@ -85,7 +85,7 @@ def mamba_ssd(
     C: jnp.ndarray,           # (b, s, n)
     chunk: int = 64,
     head_block: int = 8,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     b, s, h, p = x.shape
     n = B.shape[-1]

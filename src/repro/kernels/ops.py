@@ -1,12 +1,15 @@
 """jit'd public wrappers for the Pallas kernels.
 
-``interpret=True`` (default here) runs the kernel bodies in Python on CPU
-for validation; on a real TPU pass ``interpret=False``.
+``interpret=None`` (the default) is decided here, in one place, by
+:func:`default_interpret`: compiled on a TPU, the Python interpreter on
+any other backend (CPU tests).  Pass a bool only to override it, e.g. to
+compile a kernel for a described TPU from a CPU process.
 """
 from __future__ import annotations
 
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from .flash_attention import flash_attention as _flash
@@ -17,9 +20,18 @@ from .wire_codec import dequant_blend as _dequant_blend
 from .wire_codec import int8_quantize as _int8_quantize
 
 
+def default_interpret() -> bool:
+    """Pallas kernels compile on a TPU and are interpreted elsewhere."""
+    return jax.default_backend() != "tpu"
+
+
+def _interpret(interpret: Optional[bool]) -> bool:
+    return default_interpret() if interpret is None else bool(interpret)
+
+
 def flash_attention(q, k, v, q_positions, kv_positions, *, causal=True,
                     window=0, kv_len=None, blk_q=128, blk_k=128,
-                    interpret=True, skip_upper=False):
+                    interpret=None, skip_upper=False):
     if kv_len is not None:
         # fold the valid-length mask into kv positions (int32-max = masked)
         kv_positions = jnp.where(
@@ -29,41 +41,41 @@ def flash_attention(q, k, v, q_positions, kv_positions, *, causal=True,
     return _flash(q, k, v, q_positions.astype(jnp.int32),
                   kv_positions.astype(jnp.int32), causal=causal,
                   window=window, blk_q=blk_q, blk_k=blk_k,
-                  interpret=interpret, skip_upper=skip_upper)
+                  interpret=_interpret(interpret), skip_upper=skip_upper)
 
 
 def latent_blend(preds, weights, normalizer, starts: Tuple[int, ...],
-                 window: int, extent: int, *, blk_f=512, interpret=True):
+                 window: int, extent: int, *, blk_f=512, interpret=None):
     return _blend(preds, weights, normalizer, tuple(int(s) for s in starts),
-                  window, extent, blk_f=blk_f, interpret=interpret)
+                  window, extent, blk_f=blk_f,
+                  interpret=_interpret(interpret))
 
 
-def int8_quantize(x, *, qmax=127, blk_r=256, interpret=True):
+def int8_quantize(x, *, qmax=127, blk_r=256, blk_f=2048, interpret=None):
     """(wire int8, scale (1,1)) — fused per-slab max-abs + quantize."""
-    return _int8_quantize(x, qmax=qmax, blk_r=blk_r, interpret=interpret)
+    return _int8_quantize(x, qmax=qmax, blk_r=blk_r, blk_f=blk_f,
+                          interpret=_interpret(interpret))
 
 
 def dequant_blend(wire, scales, weights, normalizer, starts: Tuple[int, ...],
-                  window: int, extent: int, *, blk_f=512, interpret=True,
+                  window: int, extent: int, *, blk_f=512, interpret=None,
                   out_dtype=None):
     """Fused int8 dequantize + position-aware blend (latent_blend twin)."""
-    import jax.numpy as _jnp
-
     return _dequant_blend(
         wire, scales.reshape(-1), weights, normalizer,
         tuple(int(s) for s in starts), window, extent, blk_f=blk_f,
-        interpret=interpret,
-        out_dtype=out_dtype if out_dtype is not None else _jnp.float32,
+        interpret=_interpret(interpret),
+        out_dtype=out_dtype if out_dtype is not None else jnp.float32,
     )
 
 
 def guidance_update(z, cond, uncond, w: float, dt: float, *,
-                    blk=65536, interpret=True):
+                    blk=65536, interpret=None):
     return _guidance(z, cond, uncond, float(w), float(dt), blk=blk,
-                     interpret=interpret)
+                     interpret=_interpret(interpret))
 
 
 def mamba_ssd(x, log_decay, scale, B, C, *, chunk=64, head_block=8,
-              interpret=True):
+              interpret=None):
     return _ssd(x, log_decay, scale, B, C, chunk=chunk,
-                head_block=head_block, interpret=interpret)
+                head_block=head_block, interpret=_interpret(interpret))

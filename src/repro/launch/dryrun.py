@@ -376,7 +376,6 @@ def lower_cell(
         attn_seq = parallel.tp_axis
     if shape.kind == "vdm_generate" and lp_impl == "gspmd" and             cfg.num_heads % tp_size:
         attn_seq = parallel.tp_axis
-    from repro import compat
     from contextlib import nullcontext
 
     def _span(name, **kw):
@@ -385,7 +384,7 @@ def lower_cell(
         return recorder.span(name, cat="dryrun", arch=arch,
                              shape=shape_name, **kw)
 
-    with compat.set_mesh(mesh), actctx.batch_axes(dp_for_ctx, attn_seq=attn_seq), \
+    with jax.set_mesh(mesh), actctx.batch_axes(dp_for_ctx, attn_seq=attn_seq), \
             _span("dryrun.cell", mesh=rec["mesh"]):
         if shape.kind == "train":
             train_step = make_train_step(model, parallel)

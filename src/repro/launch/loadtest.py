@@ -1,7 +1,7 @@
 """Serving load-test CLI — offered-load replay + SLO report.
 
   PYTHONPATH=src python -m repro.launch.loadtest --rate 2 --requests 12 \
-      --steps 4 --partitions 2 [--arrivals poisson] [--seed 0] \
+      --steps 4 --partitions 2 [--reduced] [--arrivals poisson] [--seed 0] \
       [--mix 'clip,shape=6x8x12,priority=interactive;...'] \
       [--slo 'interactive:30@0.99,standard:120@0.95'] \
       [--trace-out artifacts/load_trace.json] \
@@ -60,6 +60,9 @@ def _add_engine_args(ap: argparse.ArgumentParser) -> None:
     ap.add_argument("--psnr-floor", type=float, default=None)
     ap.add_argument("--mesh", default=None,
                     help="MxT hybrid mesh; M must equal --partitions")
+    ap.add_argument("--reduced", action="store_true",
+                    help="2-block, 128-wide f32 stand-in of WAN2.1-1.3B "
+                         "(CPU smoke runs); default: published width")
     ap.add_argument("--max-queue", type=int, default=None,
                     help="bound each engine queue: submit raises "
                          "QueueFull beyond this many queued requests "
@@ -167,8 +170,8 @@ def main(argv=None):
 
     import jax
 
-    from repro import models
-    from repro.configs import get_config
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.launch.serve import load_model
     from repro.models import dit
     from repro.obs import FlightRecorder
     from repro.serving.engine import LPServingEngine
@@ -189,13 +192,7 @@ def main(argv=None):
           f"({args.arrivals}, seed={args.seed}) "
           f"digest={workload_digest(workload)[:12]}")
 
-    cfg = get_config("wan21-dit-1.3b").reduced()
-    model = models.build(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-
-    def fwd(p, z, t, c, cfg_model):
-        return dit.forward(p, z, t, c, cfg_model)
-
+    enable_compile_cache()
     mesh = None
     if args.mesh:
         from repro.launch.mesh import make_hybrid_mesh, parse_mesh
@@ -205,6 +202,7 @@ def main(argv=None):
             raise SystemExit(f"--mesh {args.mesh}: LP axis {m} != "
                              f"--partitions {args.partitions}")
         mesh = make_hybrid_mesh(m, t)
+    cfg, params = load_model(args.reduced, mesh)
 
     recorder = FlightRecorder()
     slo = SLOSpec.parse(args.slo)   # None -> documented default spec
@@ -215,7 +213,7 @@ def main(argv=None):
         # built without the recorder and on a throwaway clock: the
         # warm-up batches must pollute neither the trace nor the
         # replay's virtual timeline; both are swapped in post-warm
-        return LPServingEngine(fwd, params, cfg,
+        return LPServingEngine(dit.forward, params, cfg,
                                num_partitions=args.partitions,
                                overlap_ratio=args.overlap,
                                num_steps=args.steps,

@@ -1,7 +1,13 @@
 """Serving driver CLI — LP video generation service.
 
   PYTHONPATH=src python -m repro.launch.serve --requests 4 --steps 6 \
-      --partitions 2 --overlap 0.5 [--lp-impl auto] [--wire-codec int8-residual]
+      --partitions 2 --overlap 0.5 [--latent 5x60x104] [--max-batch 4] \
+      [--lp-impl auto] [--wire-codec int8-residual] [--reduced]
+
+The model is WAN2.1-1.3B at its published width (30 blocks, d 1536,
+bf16) with random weights from a fixed seed; ``--reduced`` swaps in the
+2-block, 128-wide f32 stand-in for CPU smoke runs.  ``--latent`` is the
+latent each request denoises (T x H x W; 5x60x104 is 17 frames at 480p).
 
 Step policy (docs/step_policy.md): ``--codec-schedule auto`` lets the
 cost-model autotuner pick (engine, sigma-scheduled codec) minimizing
@@ -17,23 +23,66 @@ controls ppermute/compute overlap (default: on for hybrid meshes).
 from __future__ import annotations
 
 import argparse
+from typing import List, Tuple
 
 import jax
 
 from repro import models
 from repro.comm.codecs import CODEC_NAMES
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import dit, frontends
 from repro.serving.engine import LPServingEngine, VideoRequest
 
 
-def main(argv=None):
+def parse_latent(spec: str) -> Tuple[int, int, int]:
+    """``"5x60x104"`` -> ``(5, 60, 104)`` (latent T x H x W)."""
+    try:
+        dims = tuple(int(d) for d in spec.lower().split("x"))
+    except ValueError:
+        dims = ()
+    if len(dims) != 3 or min(dims) <= 0:
+        raise argparse.ArgumentTypeError(
+            f"--latent wants TxHxW (e.g. 5x60x104), got {spec!r}")
+    return dims
+
+
+def load_model(reduced: bool = False, mesh=None):
+    """``(cfg, params)``: WAN2.1-1.3B at published width (or its reduced
+    stand-in), random weights from seed 0.  Initialized in one compiled
+    program, straight onto ``mesh`` (replicated) when one is given, so
+    the parameters never sit on one device before being copied out."""
+    cfg = get_config("wan21-dit-1.3b")
+    if reduced:
+        cfg = cfg.reduced()
+    model = models.build(cfg)
+    shardings = None
+    if mesh is not None:
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        shardings = NamedSharding(mesh, PartitionSpec())
+    params = jax.jit(model.init, out_shardings=shardings)(
+        jax.random.PRNGKey(0))
+    return cfg, params
+
+
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--steps", type=int, default=6)
     ap.add_argument("--partitions", type=int, default=2)
     ap.add_argument("--overlap", type=float, default=0.5)
-    ap.add_argument("--frames-latent", type=int, default=6)
+    ap.add_argument("--latent", type=parse_latent, default=(5, 60, 104),
+                    metavar="TxHxW",
+                    help="request latent (default 5x60x104: 17 frames "
+                         "at 480p)")
+    ap.add_argument("--max-batch", type=int, default=4,
+                    help="requests of one shape denoised together; the "
+                         "one-chip engine runs K x 2 (CFG) rows per "
+                         "request")
+    ap.add_argument("--reduced", action="store_true",
+                    help="2-block, 128-wide f32 stand-in of the model "
+                         "(CPU smoke runs)")
     ap.add_argument("--lp-impl", default="auto",
                     choices=["auto", "uniform", "shard_map", "halo",
                              "halo_hybrid"],
@@ -87,17 +136,14 @@ def main(argv=None):
     ap.add_argument("--metrics-out", default=None,
                     help="write a metrics snapshot here (.prom/.txt -> "
                          "Prometheus text, else JSONL)")
-    args = ap.parse_args(argv)
+    return ap
+
+
+def build_engine(args, recorder=None):
+    """``(cfg, engine)`` for parsed CLI ``args``: the model, the mesh
+    (``--mesh``) and the LP serving engine, as ``main`` serves them."""
     if args.codec_schedule and args.wire_codec:
-        ap.error("--codec-schedule and --wire-codec are exclusive")
-
-    cfg = get_config("wan21-dit-1.3b").reduced()
-    model = models.build(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-
-    def fwd(p, z, t, c, cfg_model):
-        return dit.forward(p, z, t, c, cfg_model)
-
+        raise SystemExit("--codec-schedule and --wire-codec are exclusive")
     mesh = None
     if args.mesh:
         from repro.launch.mesh import make_hybrid_mesh, parse_mesh
@@ -108,17 +154,12 @@ def main(argv=None):
                 f"--mesh {args.mesh}: LP axis {m} != --partitions "
                 f"{args.partitions}")
         mesh = make_hybrid_mesh(m, t)
-
-    recorder = None
-    if args.trace_out or args.metrics_out:
-        from repro.obs import FlightRecorder
-
-        recorder = FlightRecorder()
-
-    engine = LPServingEngine(fwd, params, cfg,
+    cfg, params = load_model(args.reduced, mesh)
+    engine = LPServingEngine(dit.forward, params, cfg,
                              num_partitions=args.partitions,
                              overlap_ratio=args.overlap,
                              num_steps=args.steps,
+                             max_batch=args.max_batch,
                              lp_impl=args.lp_impl,
                              wire_codec=args.wire_codec,
                              codec_schedule=args.codec_schedule,
@@ -130,6 +171,33 @@ def main(argv=None):
                              inject_fault=args.inject_fault,
                              wire_nan_guard=args.wire_nan_guard,
                              recorder=recorder)
+    return cfg, engine
+
+
+def make_requests(args, cfg) -> List[VideoRequest]:
+    """The ``--requests`` requests ``main`` submits, seeded by index."""
+    return [
+        VideoRequest(
+            request_id=i,
+            context=frontends.text_context(jax.random.PRNGKey(i), 1, cfg),
+            latent_shape=tuple(args.latent),
+            seed=i,
+        )
+        for i in range(args.requests)
+    ]
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
+    recorder = None
+    if args.trace_out or args.metrics_out:
+        from repro.obs import FlightRecorder
+
+        recorder = FlightRecorder()
+    cfg, engine = build_engine(args, recorder)
+    print(f"config: {cfg.name} blocks={cfg.num_layers} d={cfg.d_model} "
+          f"heads={cfg.num_heads} ffn={cfg.d_ff} dtype={cfg.dtype}")
     print(f"engine: lp_impl={engine.lp_impl} codec={engine.codec.name} "
           f"tp={engine.tp} wire_shard={engine.wire_shard} "
           f"eager_sends={engine.eager_sends}")
@@ -139,13 +207,8 @@ def main(argv=None):
         print(f"fault drill: {engine._fault_plan.describe()} "
               f"(elastic={engine.elastic}, "
               f"nan_guard={engine.wire_nan_guard})")
-    for i in range(args.requests):
-        engine.submit(VideoRequest(
-            request_id=i,
-            context=frontends.text_context(jax.random.PRNGKey(i), 1, cfg),
-            latent_shape=(args.frames_latent, 8, 12),
-            seed=i,
-        ))
+    for req in make_requests(args, cfg):
+        engine.submit(req)
     results = engine.run()
     for r in sorted(results, key=lambda x: x.request_id):
         resumed = f" resumed_from={r.resumed_from_step}" if r.restarts else ""

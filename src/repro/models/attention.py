@@ -144,7 +144,7 @@ def attention(
     kv_len=None,
     kv_chunk: int = 2048,
     use_pallas: bool = False,
-    pallas_interpret: bool = True,
+    pallas_interpret: Optional[bool] = None,
 ):
     """Dispatch: Pallas flash kernel (TPU target) or chunked jnp."""
     if use_pallas:

@@ -208,6 +208,7 @@ class LPServingEngine:
     ):
         self.dit_forward = dit_forward
         self.params = params
+        self._params_mesh = None        # mesh self.params is placed on
         self.cfg = cfg
         self.K = num_partitions
         self.r = overlap_ratio
@@ -460,7 +461,7 @@ class LPServingEngine:
                 )
         # Hoisted out of the batch loop: conditioning is traced, so this
         # closure (and every step it compiles) is batch-independent.
-        self._guided = make_guided_step_denoiser(dit_forward, params, cfg)
+        self._guided = make_guided_step_denoiser(dit_forward, cfg)
         self._compiler = LPStepCompiler(
             denoise_fn=self._guided,
             update_fn=self._sampler.update,
@@ -566,6 +567,20 @@ class LPServingEngine:
             compiler_codec = self.codec
         # else: uniform vmapped engine (psum-equivalent math, no wire)
         return forward, forward_factory, compiler_codec
+
+    def _step_params(self):
+        """The parameters as the compiled step takes them: as given
+        without a mesh, replicated over the mesh otherwise.  Placed once
+        per mesh (again after an eviction shrinks it), so no step
+        re-sends them, and the unplaced copy is dropped so a device
+        never holds the parameters twice."""
+        if self.mesh is not None and self._params_mesh is not self.mesh:
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            self.params = jax.device_put(
+                self.params, NamedSharding(self.mesh, PartitionSpec()))
+            self._params_mesh = self.mesh
+        return self.params
 
     # ------------------------------------------------------------- queue
     def _rlabels(self) -> Dict[str, str]:
@@ -912,7 +927,10 @@ class LPServingEngine:
                     None, z_T, self._sampler, self.num_steps, self.K,
                     self.r, self.cfg.patch_sizes, (1, 2, 3),
                     uniform=self.uniform,
-                    extras=(ctx, null_ctx, guidance),
+                    # read per dispatch: an eviction in the step hook
+                    # moves the mesh, and the parameters with it
+                    extras=lambda: (self._step_params(), ctx, null_ctx,
+                                    guidance),
                     compiler=self._compiler,
                     step_hook=self._step_hook(), snapshot=snapshot,
                     recorder=rec,

@@ -57,20 +57,16 @@ MULTIDEV_SCRIPT = textwrap.dedent(
     def denoise(x):
         return jnp.tanh(x) * 0.5 + x
     ref = lp_forward_uniform(denoise, z, plan, axis=0)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         # compile once, reuse the AOT executable for both the value check
         # and the collective check (compiles are slow on tiny CPU quotas)
         compiled_sm = jax.jit(
             lambda zz: lp_forward_shard_map(denoise, zz, plan, 0, mesh)
         ).lower(z).compile()
         out_sm = compiled_sm(z)
-    # GSPMD engine: single-axis mesh — the 0.4.x partitioner double-counts
-    # the stacked-axis reduce when a second (replicated) mesh axis exists
-    # (see lp_forward_gspmd docstring); newer jax handles it via AxisType.
-    mesh_gs = (mesh if compat.AxisType is not None
-               else compat.make_mesh((4,), ("data",)))
+    # GSPMD engine on the same two-axis mesh (Auto axis types)
     out_gs = jax.jit(
-        lambda zz: lp_forward_gspmd(denoise, zz, plan, 0, mesh_gs)
+        lambda zz: lp_forward_gspmd(denoise, zz, plan, 0, mesh)
     )(z)
     np.testing.assert_allclose(np.asarray(out_sm), np.asarray(ref), atol=1e-5)
     np.testing.assert_allclose(np.asarray(out_gs), np.asarray(ref), atol=1e-5)

@@ -112,7 +112,7 @@ def test_seq_parallel_decode_attention_multidevice():
             # GQA layout: repeat q heads into kv grouping handled inside
             return seq_parallel_decode_attention(q, kl, vl, pl_, posn, "data")
 
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             per_shard, mesh=mesh,
             in_specs=(P(), P(None, "data"), P(None, "data"),
                       P(None, "data"), P()),

@@ -119,8 +119,8 @@ def test_collectives_inside_while_multiply():
                 return c + jax.lax.psum(c, "x"), None
             out, _ = jax.lax.scan(body, v, None, length=7)
             return out
-        sm = compat.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
-                              check_vma=False)
+        sm = jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
+                           check_vma=False)
         hlo = jax.jit(sm).lower(
             jax.ShapeDtypeStruct((128,), jnp.float32)).compile().as_text()
         a = analyze(hlo)

@@ -126,12 +126,16 @@ HYBRID_SCRIPT = textwrap.dedent(
     out = lp_denoise(None, z5, sampler, 12, M, 0.5, (1, 2, 2), (1, 2, 3),
                      uniform=True, compiler=comp)
     assert np.isfinite(np.asarray(out)).all()
-    assert traces["n"] <= 3, traces
+    # counted on compiled steps, not Python traces: the first step's
+    # latent is off the mesh and later ones come back mesh-replicated,
+    # and jit keys its trace on that, so the first rotation dim is
+    # traced once more by jax itself inside the same compiled step
     assert comp.compiles <= 3 and comp.hits >= 9, (comp.compiles, comp.hits)
-    before = comp.compiles
+    before, traced = comp.compiles, traces["n"]
     lp_denoise(None, z5, sampler, 12, M, 0.5, (1, 2, 2), (1, 2, 3),
                uniform=True, compiler=comp)
     assert comp.compiles == before  # second run fully cache-served
+    assert traces["n"] == traced, traces  # ... and never retraced
     print("COMPILES-OK", comp.compiles, comp.hits)
     """
 )

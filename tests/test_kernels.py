@@ -167,6 +167,28 @@ def test_latent_blend_property(K, n_patches, patch, r, F):
                                atol=3e-5, rtol=3e-5)
 
 
+@pytest.mark.parametrize("K,extent,patch,r,F,blk_f", [
+    (2, 60, 2, 0.5, 1000, 512),     # the one-chip H window, F padded
+    (4, 104, 2, 0.5, 700, 512),     # unaligned starts (0, 14, 40, 54)
+    (4, 21, 1, 0.5, 96, 32),        # 81-frame T windows, several F blocks
+])
+def test_latent_blend_bit_identical_to_oracle(K, extent, patch, r, F, blk_f):
+    """Interpret mode reproduces the scatter-add oracle bit for bit: the
+    kernel's f32 multiply-adds run in the same partition order."""
+    rng = np.random.default_rng(K * 13 + extent)
+    plan, preds, w, z = _mk_blend(rng, K, extent, patch, r, F)
+    out = ops.latent_blend(preds, w, z, plan.starts, plan.window,
+                           plan.extent, blk_f=blk_f)
+    want = ref.latent_blend_ref(preds, w, z, plan.starts, plan.window,
+                                plan.extent)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+
+
+def test_kernels_interpret_only_off_tpu():
+    """One place decides interpret mode, from the backend."""
+    assert ops.default_interpret() == (jax.default_backend() != "tpu")
+
+
 # --------------------------------------------------------------- guidance
 @pytest.mark.parametrize("shape", [(4, 8, 8, 4), (1, 13, 60, 104, 16)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -13,7 +13,7 @@ the suite by adding a row here and a branch in the subprocess runner):
 
   * ``psum``        — ``core/spmd.lp_forward_shard_map`` (fp32 wire only)
   * ``gspmd``       — ``core/spmd.lp_forward_gspmd`` (stateless codecs,
-                      value-faithful blend; single-axis mesh on jax 0.4.x)
+                      value-faithful blend)
   * ``halo``        — ``core/spmd.lp_forward_halo`` (all codecs)
   * ``halo_hybrid`` — ``core/hybrid.lp_forward_halo_hybrid`` on a
                       ``(K, 2)`` mesh with a Megatron-style TP Phi_m

@@ -598,10 +598,47 @@ def test_int8_quantize_kernel_matches_codec_encode():
     from repro.kernels import ops
 
     x = jnp.asarray(rng.normal(size=(26, 65)).astype(np.float32))
-    wire, scale = ops.int8_quantize(x, interpret=True)
+    wire, scale = ops.int8_quantize(x)
     w2, (s2,) = IntCodec(name="int8", bits=8.0).encode(x)
     np.testing.assert_array_equal(np.asarray(wire), np.asarray(w2))
     assert float(jnp.abs(scale[0, 0] - s2.reshape(()))) == 0.0
+
+
+@pytest.mark.parametrize("shape,blk_r,blk_f", [
+    ((100, 300), 32, 128),     # several row AND column blocks, both padded
+    ((12, 4100), 256, 2048),   # few rows, long rows: tiled along F only
+])
+def test_int8_quantize_kernel_tiled_matches_codec_encode(shape, blk_r,
+                                                         blk_f):
+    rng = np.random.default_rng(11)
+    from repro.kernels import ops
+
+    x = jnp.asarray(rng.normal(size=shape).astype(np.float32))
+    wire, scale = ops.int8_quantize(x, blk_r=blk_r, blk_f=blk_f)
+    w2, (s2,) = IntCodec(name="int8", bits=8.0).encode(x)
+    assert wire.shape == shape
+    np.testing.assert_array_equal(np.asarray(wire), np.asarray(w2))
+    assert float(jnp.abs(scale[0, 0] - s2.reshape(()))) == 0.0
+
+
+def test_dequant_blend_bit_identical_to_oracle():
+    """Interpret mode equals dequantize-then-blend bit for bit."""
+    from repro.kernels import ops, ref
+    from repro.core.spmd import window_weights
+
+    rng = np.random.default_rng(5)
+    plan = plan_uniform(104, 2, 4, 0.5)
+    wire = jnp.asarray(rng.integers(-127, 128, size=(4, plan.window, 300)),
+                       jnp.int8)
+    scales = jnp.asarray(rng.uniform(0.01, 0.02, size=(4,)), jnp.float32)
+    w = jnp.asarray(window_weights(plan))
+    z = jnp.asarray(plan.normalizer())
+    out = ops.dequant_blend(wire, scales, w, z, plan.starts, plan.window,
+                            plan.extent, blk_f=128)
+    deq = wire.astype(jnp.float32) * scales[:, None, None]
+    want = ref.latent_blend_ref(deq, w, z, plan.starts, plan.window,
+                                plan.extent)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
 
 
 @pytest.mark.parametrize("axis,shape", [
