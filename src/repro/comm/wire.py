@@ -193,6 +193,7 @@ def _gather_msg(wire, meta, axis_name, shard_axis=None, shard_size=1):
 
 
 # ----------------------------------------------------------- SPMD pieces
+@jax.named_scope("lp.halo")
 def compressed_halo_exchange(
     wpred: jnp.ndarray,
     spec: HaloSpec,
@@ -323,6 +324,7 @@ def compressed_halo_exchange(
     return acc, new_state
 
 
+@jax.named_scope("lp.halo")
 def compressed_core_gather(
     core: jnp.ndarray,
     rank: jnp.ndarray,
@@ -378,6 +380,7 @@ def compressed_core_gather(
 
 
 # ---------------------------------------------------- single-process mirror
+@jax.named_scope("lp.halo")
 def simulate_halo_forward(
     denoise_fn,
     z: jnp.ndarray,

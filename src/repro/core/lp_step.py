@@ -25,12 +25,13 @@ from __future__ import annotations
 import dataclasses
 import time
 from collections import OrderedDict
-from contextlib import nullcontext
 from typing import Any, Callable, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.obs.trace import span
 
 from .partition import PartitionPlan, extract, plan_partition
 from .reconstruct import reconstruct
@@ -94,7 +95,8 @@ def lp_forward(
     """One LP forward pass with a prebuilt (paper-exact) partition plan."""
     preds = []
     for k in range(plan.num_partitions):
-        sub = extract(z, plan, k, axis)
+        with jax.named_scope("lp.window"):
+            sub = extract(z, plan, k, axis)
         pred = denoise_fn(sub)
         if pred.shape != sub.shape:
             raise ValueError(
@@ -134,6 +136,20 @@ def _abstract_sig(tree: Any) -> Tuple:
         treedef,
         tuple((jnp.shape(l), jnp.result_type(l).name) for l in leaves),
     )
+
+
+def _abstract(x) -> jax.ShapeDtypeStruct:
+    """An argument as ``jit`` saw it: shape, dtype and, for an array
+    committed to its devices, its sharding.  Readable from a donated
+    (deleted) array too."""
+    committed = isinstance(x, jax.Array) and x.committed
+    return jax.ShapeDtypeStruct(np.shape(x), jnp.result_type(x),
+                                sharding=x.sharding if committed else None)
+
+
+# rotation dim -> the name of its step program (``jit_lp_step_T`` in HLO
+# and in a profile's ``XLA Modules`` line)
+DIM_NAMES = "THW"
 
 
 class LPStepCompiler:
@@ -255,6 +271,9 @@ class LPStepCompiler:
                 )
         self.codec = codec
         self._cache: "OrderedDict[Tuple, Callable]" = OrderedDict()
+        # id(step program) -> (program, abstract args of each executable
+        # it compiled), for :meth:`programs`
+        self._ran: dict = {}
         self.compiles = 0
         self.hits = 0
         # re-planning bookkeeping: the epoch bumps on every geometry
@@ -420,7 +439,11 @@ class LPStepCompiler:
             return cached
         axis = self.spatial_axes[dim]
         plan = self._plan(dim, z.shape[axis])
-        den, upd = self.denoise_fn, self.update_fn
+        den = self.denoise_fn
+
+        def upd(z, pred, sc):
+            with jax.named_scope("lp.update"):
+                return self.update_fn(z, pred, sc)
 
         if codec is not None and codec.stateful:
             # codec state rides the scan carry next to z — the step stays
@@ -461,12 +484,44 @@ class LPStepCompiler:
                 out, _ = jax.lax.scan(body, zc, (ts, scs))
                 return out
 
+        step.__name__ = step.__qualname__ = f"lp_step_{DIM_NAMES[dim]}"
         fn = jax.jit(step, donate_argnums=(0,) if self.donate else ())
         self._cache[key] = fn
         if len(self._cache) > self.maxsize:
-            self._cache.popitem(last=False)
+            _, old = self._cache.popitem(last=False)
+            self._ran.pop(id(old), None)
         self.compiles += 1
         return fn
+
+    def dispatch(self, fn: Callable, *args):
+        """Run a step program from :meth:`step_fn`.  When the call
+        compiles a new executable (a new program, or jit retracing one
+        for arguments placed differently), its abstract arguments are
+        kept for :meth:`programs`; a warm call only compares two counts.
+        """
+        n = fn._cache_size()
+        out = fn(*args)
+        if fn._cache_size() != n:
+            ran = self._ran.setdefault(id(fn), (fn, []))
+            ran[1].append(jax.tree.map(_abstract, args))
+        return out
+
+    def programs(self) -> list:
+        """``[(module name, optimized HLO text)]`` of every executable
+        the step programs in the cache compiled, for mapping a profile's
+        device ops to their source scopes (``repro.obs.scopes``).
+
+        Lowers and compiles each one again from its abstract arguments:
+        a load from JAX's in-memory or persistent compilation cache
+        where it holds the program, a full compile otherwise.  Call it
+        outside any timed window.
+        """
+        out = []
+        for fn, sigs in self._ran.values():
+            for sig in sigs:
+                text = fn.lower(*sig).compile().as_text()
+                out.append((text.split(None, 2)[1].rstrip(","), text))
+        return out
 
 
 def lp_denoise(
@@ -534,21 +589,28 @@ def lp_denoise(
     latent, and the resumed steps re-derive dims from the compiler's
     current K.
 
-    ``recorder`` (a ``repro.obs.FlightRecorder``; duck-typed so core
-    never imports obs) wraps every compiled dispatch in a trace span +
-    ``jax.profiler.TraceAnnotation`` and feeds the run/step latency
-    histograms.  It is pure host state — NEVER passed into the jitted
-    step and never part of the compile cache key — so enabling it can
-    change neither compile counts nor numerics
-    (``benchmarks/obs_overhead.py`` gates both).  Per-step wire bytes
-    are NOT probed here: the serving engine derives them by replaying
-    ``comm_model`` (``repro.obs.account``) against the executed
-    geometry.  Note the spans block on the dispatched value, so device
-    work is attributed to its own span instead of the next one.
+    Every compiled dispatch runs inside a ``denoise.run`` span
+    (``denoise.step`` on the unfused path; ``dim`` and the step range in
+    its args) and every boundary copy inside a ``snapshot.record`` span,
+    through ``repro.obs.trace.span``: on the profiler's clock always, so
+    a device profile shows what the host did between steps.
+
+    ``recorder`` (a ``repro.obs.FlightRecorder``) adds the spans to its
+    Chrome trace and feeds the run/step latency histograms.  It is pure
+    host state — NEVER passed into the jitted step and never part of the
+    compile cache key — so enabling it can change neither compile counts
+    nor numerics (``benchmarks/obs_overhead.py`` gates both).  Per-step
+    wire bytes are NOT probed here: the serving engine derives them by
+    replaying ``comm_model`` (``repro.obs.account``) against the
+    executed geometry.  With a recorder, and only then, each dispatch
+    blocks on its value before its span closes, so the run wall that
+    ``record_run`` keeps (``denoise.run_s``, and the measured side of
+    ``wire.reconcile``) is device time and not the enqueue.
     """
     if step_hook is not None:
         fuse_scan = False
     get_extras = extras if callable(extras) else (lambda: extras)
+    trace = getattr(recorder, "trace", None)
     comp = compiler
     if comp is None:
         if denoise_fn is None:
@@ -593,6 +655,13 @@ def lp_denoise(
                 f"no latent dim has >= {comp.num_partitions} patches; reduce K"
             )
         return dims
+
+    def _snapshot(step: int, z, epoch: int) -> None:
+        # the host copy of the latent: a sync with the device
+        with span("snapshot.record", trace, step=step, epoch=epoch):
+            snapshot.record(step, z, epoch)
+        if recorder is not None:
+            recorder.record_snapshot(step)
 
     dims = _dims()
     start = 0
@@ -639,32 +708,24 @@ def lp_denoise(
             scs = [sampler.step_scalars(i) for i in idxs]
             st = comp.init_codec_state(dim, z, seg_codec) if stateful else None
             ck_name = ck or getattr(comp.codec, "name", "none")
-            span = (nullcontext() if recorder is None else
-                    recorder.device_span("denoise.run", dim=dim,
-                                         codec=ck_name, start=idxs[0],
-                                         stop=idxs[-1], n=len(idxs),
-                                         epoch=comp.plan_epoch))
             t0 = time.perf_counter()
-            with span:
+            with span("denoise.run", trace, cat="denoise", dim=dim,
+                      codec=ck_name, start=idxs[0], stop=idxs[-1],
+                      n=len(idxs), epoch=comp.plan_epoch):
                 extras = get_extras()
                 if len(idxs) == 1:
-                    fn = comp.step_fn(dim, z, 1, scs[0], extras,
-                                      codec=seg_codec)
-                    if stateful:
-                        z, _ = fn(z, st, ts[0], scs[0], extras)
-                    else:
-                        z = fn(z, ts[0], scs[0], extras)
+                    t, sc = ts[0], scs[0]
                 else:
-                    ts_arr = jnp.asarray(np.stack(ts))
-                    scs_arr = jax.tree.map(
+                    t = jnp.asarray(np.stack(ts))
+                    sc = jax.tree.map(
                         lambda *xs: jnp.asarray(np.stack(xs)), *scs
                     )
-                    fn = comp.step_fn(dim, z, len(idxs), scs_arr, extras,
-                                      codec=seg_codec)
-                    if stateful:
-                        z, _ = fn(z, st, ts_arr, scs_arr, extras)
-                    else:
-                        z = fn(z, ts_arr, scs_arr, extras)
+                fn = comp.step_fn(dim, z, len(idxs), sc, extras,
+                                  codec=seg_codec)
+                if stateful:
+                    z, _ = comp.dispatch(fn, z, st, t, sc, extras)
+                else:
+                    z = comp.dispatch(fn, z, t, sc, extras)
                 if recorder is not None:
                     jax.block_until_ready(z)
             if recorder is not None:
@@ -673,9 +734,7 @@ def lp_denoise(
                                     dim=dim, codec=ck_name,
                                     epoch=comp.plan_epoch)
             if snapshot is not None and idxs[-1] < num_steps:
-                snapshot.record(idxs[-1], z, comp.plan_epoch)
-                if recorder is not None:
-                    recorder.record_snapshot(idxs[-1])
+                _snapshot(idxs[-1], z, comp.plan_epoch)
         return z
 
     # Unfused (step_hook) path: one compiled step per call, codec state
@@ -708,9 +767,7 @@ def lp_denoise(
                 # boundary with the NEW epoch — a second fault resumes
                 # from a boundary whose epoch matches the geometry its
                 # replay will re-derive, never a pre-replan stamp.
-                snapshot.record(i - 1, z, cur_epoch)
-                if recorder is not None:
-                    recorder.record_snapshot(i - 1)
+                _snapshot(i - 1, z, cur_epoch)
         dim = rotation_dim(i, dims)
         seg_codec = step_codecs[i - 1]
         ck = _codec_key(seg_codec)
@@ -722,17 +779,16 @@ def lp_denoise(
             cur_state = comp.init_codec_state(dim, z, seg_codec)
         cur_dim, cur_codec_key = dim, ck
         ck_name = ck or getattr(comp.codec, "name", "none")
-        span = (nullcontext() if recorder is None else
-                recorder.device_span("denoise.step", dim=dim, step=i,
-                                     codec=ck_name, epoch=comp.plan_epoch))
         t0 = time.perf_counter()
-        with span:
+        with span("denoise.step", trace, cat="denoise", dim=dim, step=i,
+                  codec=ck_name, epoch=comp.plan_epoch):
             extras = get_extras()
             fn = comp.step_fn(dim, z, 1, sc, extras, codec=seg_codec)
             if stateful:
-                z, cur_state = fn(z, cur_state, t, sc, extras)
+                z, cur_state = comp.dispatch(fn, z, cur_state, t, sc,
+                                             extras)
             else:
-                z = fn(z, t, sc, extras)
+                z = comp.dispatch(fn, z, t, sc, extras)
             if recorder is not None:
                 jax.block_until_ready(z)
         if recorder is not None:
@@ -743,9 +799,7 @@ def lp_denoise(
             nxt = rotation_dim(i + 1, dims)
             nxt_ck = _codec_key(step_codecs[i])
             if nxt != dim or nxt_ck != ck:    # step i ends a run
-                snapshot.record(i, z, comp.plan_epoch)
-                if recorder is not None:
-                    recorder.record_snapshot(i)
+                _snapshot(i, z, comp.plan_epoch)
     return z
 
 

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 from typing import Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -30,6 +31,7 @@ def _shape_weight(w: np.ndarray, ndim: int, axis: int) -> jnp.ndarray:
     return jnp.asarray(w).reshape(shape)
 
 
+@jax.named_scope("lp.stitch")
 def reconstruct(
     preds: Sequence[jnp.ndarray],
     plan: PartitionPlan,

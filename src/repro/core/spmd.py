@@ -37,6 +37,7 @@ DenoiseFn = Callable[[jnp.ndarray], jnp.ndarray]
 
 
 # --------------------------------------------------------------- pure math
+@jax.named_scope("lp.window")
 def stack_windows(z: jnp.ndarray, plan: UniformPlan, axis: int) -> jnp.ndarray:
     """(K, ..., window, ...) stack of the K uniform windows of ``z``."""
     return jnp.stack(
@@ -52,6 +53,7 @@ def window_weights(plan: UniformPlan) -> np.ndarray:
     return np.stack([plan.weight_1d(k) for k in range(plan.num_partitions)])
 
 
+@jax.named_scope("lp.stitch")
 def blend_windows(
     preds: jnp.ndarray, plan: UniformPlan, axis: int,
     use_kernel: bool | None = None,
@@ -105,6 +107,7 @@ def blend_windows(
     return (acc / norm).astype(preds.dtype)
 
 
+@jax.named_scope("lp.stitch")
 def blend_windows_coded(
     preds: jnp.ndarray, plan: UniformPlan, axis: int,
     codec="int8", use_kernel: bool | None = None,
@@ -253,10 +256,13 @@ def lp_forward_shard_map(
 
     other_axes = tuple(n for n in mesh.axis_names if n != lp_axis)
 
+    @jax.named_scope("lp.stitch")
     def per_device(z_rep: jnp.ndarray) -> jnp.ndarray:
         k = jax.lax.axis_index(lp_axis)
         start = starts[k]
-        window = jax.lax.dynamic_slice_in_dim(z_rep, start, plan.window, axis)
+        with jax.named_scope("lp.window"):
+            window = jax.lax.dynamic_slice_in_dim(z_rep, start, plan.window,
+                                                  axis)
         pred = denoise_fn(window).astype(jnp.float32)
         wshape = [1] * pred.ndim
         wshape[axis] = plan.window
@@ -264,7 +270,8 @@ def lp_forward_shard_map(
         out_shape = list(z_rep.shape)
         buf = jnp.zeros(out_shape, jnp.float32)
         buf = jax.lax.dynamic_update_slice_in_dim(buf, pred, start, axis)
-        buf = jax.lax.psum(buf, lp_axis)  # latent reconstruction (Eq. 15)
+        with jax.named_scope("lp.halo"):
+            buf = jax.lax.psum(buf, lp_axis)  # reconstruction (Eq. 15)
         nshape = [1] * buf.ndim
         nshape[axis] = plan.extent
         return (buf / norm.reshape(nshape)).astype(z_rep.dtype)
@@ -423,8 +430,13 @@ def lp_forward_halo(
                 "comm.wire.init_halo_wire_state"
             )
 
+    # every per-device body below runs under lp.stitch; the window slice
+    # (lp.window), the exchange and the core gather (lp.halo) and the
+    # denoiser (dit.*) name their own ops
     def _weighted_window(z_rep, k):
-        window = jax.lax.dynamic_slice_in_dim(z_rep, starts[k], plan.window, axis)
+        with jax.named_scope("lp.window"):
+            window = jax.lax.dynamic_slice_in_dim(z_rep, starts[k],
+                                                  plan.window, axis)
         pred = denoise_fn(window).astype(jnp.float32)
         wshape = [1] * pred.ndim
         wshape[axis] = plan.window
@@ -440,6 +452,7 @@ def lp_forward_halo(
             )
         return jnp.moveaxis(out, 0, axis).astype(dtype)
 
+    @jax.named_scope("lp.halo")
     def _core_gather_raw(core: jnp.ndarray) -> jnp.ndarray:
         """Uncoded core all-gather, wire-sharded when shard_axis is set:
         each tp rank gathers only its 1/T chunk over the lp ring, then
@@ -449,6 +462,7 @@ def lp_forward_halo(
         return sharded_all_gather(core, lp_axis, shard_axis, shard_size)
 
     if codec is None:
+        @jax.named_scope("lp.stitch")
         def per_device(z_rep: jnp.ndarray) -> jnp.ndarray:
             k = jax.lax.axis_index(lp_axis)
             wpred = _weighted_window(z_rep, k)
@@ -477,6 +491,7 @@ def lp_forward_halo(
     )
 
     if not codec.stateful:
+        @jax.named_scope("lp.stitch")
         def per_device_codec(z_rep: jnp.ndarray) -> jnp.ndarray:
             k = jax.lax.axis_index(lp_axis)
             wpred = _weighted_window(z_rep, k)
@@ -503,6 +518,7 @@ def lp_forward_halo(
         )
         return fn(z)
 
+    @jax.named_scope("lp.stitch")
     def per_device_stateful(z_rep: jnp.ndarray, state):
         k = jax.lax.axis_index(lp_axis)
         st = jax.tree.map(lambda s: s[0], state)  # drop the lp-axis dim

@@ -1,14 +1,16 @@
 """Classifier-free guidance (paper Eq. 2/4)."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
 def cfg_combine(cond: jnp.ndarray, uncond: jnp.ndarray, w: float) -> jnp.ndarray:
     """f~ = f_uncond + w (f_cond - f_uncond)."""
-    return (uncond.astype(jnp.float32)
-            + w * (cond.astype(jnp.float32) - uncond.astype(jnp.float32))
-            ).astype(cond.dtype)
+    with jax.named_scope("dit.cfg"):
+        return (uncond.astype(jnp.float32)
+                + w * (cond.astype(jnp.float32) - uncond.astype(jnp.float32))
+                ).astype(cond.dtype)
 
 
 def cfg_batched(denoise_fn, w: float):

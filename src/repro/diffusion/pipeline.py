@@ -53,11 +53,13 @@ def make_guided_step_denoiser(dit_forward, cfg_model,
     def guided(window, t, params, context, null_context, guidance=None):
         g = guidance_default if guidance is None else guidance
         b = window.shape[0]
-        z2 = jnp.concatenate([window, window], axis=0)
-        t2 = jnp.full((2 * b,), t, jnp.float32)
-        ctx = jnp.concatenate([context, null_context], axis=0)
+        with jax.named_scope("dit.cfg"):       # the CFG pair's inputs
+            z2 = jnp.concatenate([window, window], axis=0)
+            t2 = jnp.full((2 * b,), t, jnp.float32)
+            ctx = jnp.concatenate([context, null_context], axis=0)
         pred = dit_forward(params, z2, t2, ctx, cfg_model)
-        return cfg_combine(pred[:b], pred[b:], g)
+        with jax.named_scope("dit.cfg"):
+            return cfg_combine(pred[:b], pred[b:], g)
 
     return guided
 

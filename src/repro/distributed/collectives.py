@@ -282,6 +282,7 @@ def halo_spec(plan) -> HaloSpec:
     )
 
 
+@jax.named_scope("lp.halo")
 def halo_exchange(
     wpred: jnp.ndarray,
     spec: HaloSpec,

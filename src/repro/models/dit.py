@@ -178,38 +178,56 @@ def forward(
     kv_chunk: int = 4096,
     remat: bool = False,
 ) -> jnp.ndarray:
-    """Noise prediction f(z_t, t, c) with the same shape as ``z``."""
-    B = z.shape[0]
-    tok, grid = _patchify(z, cfg)
-    x = dense(params["patch_embed"], tok.astype(_dt(cfg)))
-    ctx = dense(params["text_proj"], context.astype(_dt(cfg)))
+    """Noise prediction f(z_t, t, c) with the same shape as ``z``.
 
-    temb = sinusoidal_embedding(t.astype(jnp.float32), 256)
-    temb = dense(params["time_mlp"]["w2"],
-                 jax.nn.silu(dense(params["time_mlp"]["w1"], temb)))
-    temb = jax.nn.silu(temb)                                   # (B, time_dim)
+    Each sub-block runs under a ``jax.named_scope``
+    (``repro.obs.scopes.SCOPES``), which names its ops in the compiled
+    HLO's ``op_name`` metadata, so a profile's device ops map back to
+    the sub-block that owns them (``repro.obs.scopes.scope_map``).
+    """
+    B = z.shape[0]
+    with jax.named_scope("dit.embed"):
+        tok, grid = _patchify(z, cfg)
+        x = dense(params["patch_embed"], tok.astype(_dt(cfg)))
+        ctx = dense(params["text_proj"], context.astype(_dt(cfg)))
+
+        temb = sinusoidal_embedding(t.astype(jnp.float32), 256)
+        temb = dense(params["time_mlp"]["w2"],
+                     jax.nn.silu(dense(params["time_mlp"]["w1"], temb)))
+        temb = jax.nn.silu(temb)                               # (B, time_dim)
 
     def body(h, blk):
-        mods = dense(blk["ada"], temb).reshape(B, 6, cfg.d_model) + blk["ada_b"][None]
-        s1, b1, g1, s2, b2, g2 = [mods[:, i].astype(h.dtype) for i in range(6)]
-        hn = _modulate(rmsnorm({"scale": jnp.ones(cfg.d_model)}, h), b1, s1)
-        h = h + g1[:, None, :] * _attn(
-            blk["self_attn"], hn, cfg, grid, origin, kv_chunk=kv_chunk
-        )
-        h = h + _attn(
-            blk["cross_attn"],
-            layernorm(blk["cross_norm"], h), cfg, context=ctx,
-            kv_chunk=kv_chunk,
-        )
-        hn = _modulate(rmsnorm({"scale": jnp.ones(cfg.d_model)}, h), b2, s2)
-        h = h + g2[:, None, :] * mlp(blk["mlp"], hn)
-        return actctx.shard_batch(h), None
+        with jax.named_scope("dit.adaln"):
+            mods = (dense(blk["ada"], temb).reshape(B, 6, cfg.d_model)
+                    + blk["ada_b"][None])
+            s1, b1, g1, s2, b2, g2 = [mods[:, i].astype(h.dtype)
+                                      for i in range(6)]
+            hn = _modulate(rmsnorm({"scale": jnp.ones(cfg.d_model)}, h),
+                           b1, s1)
+        with jax.named_scope("dit.self_attn"):
+            h = h + g1[:, None, :] * _attn(
+                blk["self_attn"], hn, cfg, grid, origin, kv_chunk=kv_chunk
+            )
+        with jax.named_scope("dit.cross_attn"):
+            h = h + _attn(
+                blk["cross_attn"],
+                layernorm(blk["cross_norm"], h), cfg, context=ctx,
+                kv_chunk=kv_chunk,
+            )
+        with jax.named_scope("dit.adaln"):
+            hn = _modulate(rmsnorm({"scale": jnp.ones(cfg.d_model)}, h),
+                           b2, s2)
+        with jax.named_scope("dit.ffn"):
+            h = h + g2[:, None, :] * mlp(blk["mlp"], hn)
+            return actctx.shard_batch(h), None
 
     body_fn = jax.checkpoint(body) if remat else body
-    x, _ = pscan(body_fn, x, params["blocks"])
+    with jax.named_scope("dit.blocks"):     # the loop over the blocks
+        x, _ = pscan(body_fn, x, params["blocks"])
 
-    fmods = dense(params["final_ada"], temb).reshape(B, 2, cfg.d_model)
-    shift, scale = fmods[:, 0].astype(x.dtype), fmods[:, 1].astype(x.dtype)
-    x = _modulate(layernorm(params["final_norm"], x), shift, scale)
-    out = dense(params["head"], x)
-    return _unpatchify(out, grid, cfg, z.shape).astype(z.dtype)
+    with jax.named_scope("dit.head"):
+        fmods = dense(params["final_ada"], temb).reshape(B, 2, cfg.d_model)
+        shift, scale = fmods[:, 0].astype(x.dtype), fmods[:, 1].astype(x.dtype)
+        x = _modulate(layernorm(params["final_norm"], x), shift, scale)
+        out = dense(params["head"], x)
+        return _unpatchify(out, grid, cfg, z.shape).astype(z.dtype)

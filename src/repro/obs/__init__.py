@@ -15,7 +15,6 @@ latency with tracing on.
 """
 from __future__ import annotations
 
-from contextlib import contextmanager, nullcontext
 from typing import Any, Dict, List, Optional, Sequence
 
 from . import metrics as M
@@ -39,10 +38,10 @@ from .slo import (
     rows_from_trace,
     shed_from_trace,
 )
-from .trace import TRACE_SCHEMA, TraceRecorder, validate_trace
+from .trace import TRACE_SCHEMA, TraceRecorder, span, validate_trace
 
 __all__ = [
-    "FlightRecorder", "MetricsRegistry", "TraceRecorder",
+    "FlightRecorder", "MetricsRegistry", "TraceRecorder", "span",
     "TRACE_SCHEMA", "validate_trace", "attribute_denoise_steps",
     "step_wire_attribution", "tiered_collectives",
     "tier_for_group_size", "reconcile_segments",
@@ -84,14 +83,10 @@ class FlightRecorder:
 
     # -- trace helpers (no-op when trace plane disabled) ---------------
     def span(self, name: str, cat: str = "serve", **args: Any):
-        if self.trace is None:
-            return nullcontext()
-        return self.trace.span(name, cat=cat, **args)
-
-    def device_span(self, name: str, cat: str = "denoise", **args: Any):
-        if self.trace is None:
-            return nullcontext()
-        return self.trace.device_span(name, cat=cat, **args)
+        """:func:`repro.obs.trace.span` bound to this recorder's trace
+        plane: on the profiler's clock always, in the Chrome trace when
+        the plane is enabled."""
+        return span(name, self.trace, cat=cat, **args)
 
     def instant(self, name: str, cat: str = "serve", **args: Any) -> None:
         if self.trace is not None:
@@ -121,9 +116,12 @@ class FlightRecorder:
                    epoch: int = 0) -> None:
         """One compiled dispatch (a scan-fused run or a single step).
 
-        The span itself is emitted by ``lp_denoise``'s ``device_span``;
-        this records the measured wall for segment reconciliation and
-        feeds the run/step latency histograms.  Steps inside a fused
+        The span itself (``denoise.run`` / ``denoise.step``) is emitted
+        by ``lp_denoise``; this records the run's wall — measured to the
+        ``block_until_ready`` that ``lp_denoise`` adds only when a
+        recorder is attached — for segment reconciliation
+        (``wire.reconcile``) and feeds the run/step latency histograms
+        (``denoise.run_s``, ``denoise.step_s``).  Steps inside a fused
         ``lax.scan`` are invisible individually, so the per-step sample
         is the run wall divided evenly — documented as derived in
         docs/observability.md.
@@ -139,7 +137,8 @@ class FlightRecorder:
             self.observe(M.STEP_LATENCY_S, wall_s / n)
 
     def record_snapshot(self, step: int) -> None:
-        self.instant("snapshot.record", cat="serve", step=int(step))
+        """Counts one boundary snapshot; its ``snapshot.record`` span,
+        around the host copy, is emitted by ``lp_denoise``."""
         self.inc(M.SNAPSHOT_RECORDS)
 
     def record_resume(self, from_step: int) -> None:

@@ -9,15 +9,19 @@ I/O until :meth:`TraceRecorder.write`; the recorder must NEVER be
 visible to jit (it is plain host state, so it cannot enter a cache
 key — ``benchmarks/obs_overhead.py`` gates both properties).
 
-``device_span`` additionally enters a ``jax.profiler.TraceAnnotation``
-so that when a device profile is captured (``jax.profiler.trace``),
-the host spans line up with the device timeline under the same names.
+Every span goes through :func:`span`, which always enters a
+``jax.profiler.TraceAnnotation``: when a device profile is captured
+(``jax.profiler.trace``) the span lands on the profile's host plane,
+under the same name and on the device timeline's clock, whether or not
+a recorder is attached.  The recorder adds the Chrome-trace event.
 """
 from __future__ import annotations
 
 import json
 from contextlib import contextmanager
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from .clock import perf_us
 
@@ -51,6 +55,26 @@ def _clean(args: Dict[str, Any]) -> Dict[str, Any]:
     return {k: _jsonable(v) for k, v in args.items()}
 
 
+@contextmanager
+def span(name: str, recorder: Optional["TraceRecorder"] = None,
+         cat: str = "serve", **args: Any):
+    """The one span primitive: always on the profiler's clock, and a
+    Chrome "X" event in ``recorder`` when one is given.
+
+    ``jax.profiler.TraceAnnotation(name, **args)`` costs under a
+    microsecond when no profiler session is active, so the hot path
+    keeps its spans with tracing off; under ``jax.profiler.trace`` the
+    span is a host event named ``name`` with ``args`` as its stats.
+    """
+    t0 = perf_us() if recorder is not None else 0.0
+    try:
+        with TraceAnnotation(name, **args):
+            yield
+    finally:
+        if recorder is not None:
+            recorder.end_span(name, t0, cat=cat, **args)
+
+
 class TraceRecorder:
     """Accumulates Chrome-trace events; serialises on demand."""
 
@@ -60,11 +84,6 @@ class TraceRecorder:
         self.tid = tid
 
     # -- primitives -----------------------------------------------------
-    def begin_span(self, name: str, cat: str = "serve",
-                   **args: Any) -> float:
-        """Manual span open; pair with :meth:`end_span`."""
-        return perf_us()
-
     def end_span(self, name: str, t0_us: float, cat: str = "serve",
                  **args: Any) -> None:
         t1 = perf_us()
@@ -75,30 +94,9 @@ class TraceRecorder:
             "args": _clean(args),
         })
 
-    @contextmanager
     def span(self, name: str, cat: str = "serve", **args: Any):
-        t0 = perf_us()
-        try:
-            yield
-        finally:
-            self.end_span(name, t0, cat=cat, **args)
-
-    @contextmanager
-    def device_span(self, name: str, cat: str = "denoise", **args: Any):
-        """Span that also annotates the device timeline.
-
-        ``jax.profiler.TraceAnnotation`` is ~free when no profiler
-        session is active, and names the XLA activity when one is — so
-        host spans and device slices share a vocabulary.
-        """
-        from jax.profiler import TraceAnnotation
-
-        t0 = perf_us()
-        try:
-            with TraceAnnotation(name):
-                yield
-        finally:
-            self.end_span(name, t0, cat=cat, **args)
+        """:func:`span` bound to this recorder."""
+        return span(name, self, cat=cat, **args)
 
     def complete(self, name: str, ts_us: float, dur_us: float,
                  cat: str = "serve", **args: Any) -> None:

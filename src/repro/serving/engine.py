@@ -108,14 +108,11 @@ from repro.diffusion.pipeline import make_guided_step_denoiser
 from repro.diffusion.sampler import FlowMatchEuler
 from repro.obs import metrics as obsm
 from repro.obs.clock import perf_s
+from repro.obs.trace import span
 from repro.runtime.faults import CorruptingCodec, ReplicaDeath, \
     ServingFault, parse_fault_plan
 from repro.runtime.ft import DeviceFailure
 from repro.runtime.health import GroupHealthMonitor
-
-from contextlib import nullcontext
-
-_NULL_CM = nullcontext()
 
 
 class QueueFull(RuntimeError):
@@ -671,31 +668,37 @@ class LPServingEngine:
                 batch = by_key[self._bucket_key(oldest)][: self.max_batch]
             else:
                 return []
-        chosen = {id(r) for r in batch}
-        self._queue = [r for r in self._queue if id(r) not in chosen]
         self._batch_seq += 1
-        admit_s = float(self.clock())
-        for r in batch:
-            self._enqueued_at.pop(r.request_id, None)
-            life = self._lifecycle.get(r.request_id)
-            if life is not None:
-                life["admit_s"] = admit_s
-                life["batch_seq"] = self._batch_seq
-                life["batch_size"] = len(batch)
-        rec = self.recorder
-        if rec is not None:
-            rec.instant("batch.admit", cat="serve", size=len(batch),
+        with self._span("batch.admit", batch, size=len(batch),
                         latent_shape=batch[0].latent_shape,
-                        guidance=batch[0].guidance,
-                        request_ids=[r.request_id for r in batch],
-                        batch_seq=self._batch_seq)
-            rec.observe(obsm.BATCH_SIZE, len(batch), **self._rlabels())
-            rec.observe(obsm.BATCH_OCCUPANCY,
-                        len(batch) / max(1, self.max_batch),
-                        **self._rlabels())
-            rec.gauge(obsm.QUEUE_DEPTH, len(self._queue),
-                      **self._rlabels())
+                        guidance=batch[0].guidance):
+            chosen = {id(r) for r in batch}
+            self._queue = [r for r in self._queue if id(r) not in chosen]
+            admit_s = float(self.clock())
+            for r in batch:
+                self._enqueued_at.pop(r.request_id, None)
+                life = self._lifecycle.get(r.request_id)
+                if life is not None:
+                    life["admit_s"] = admit_s
+                    life["batch_seq"] = self._batch_seq
+                    life["batch_size"] = len(batch)
+            rec = self.recorder
+            if rec is not None:
+                rec.observe(obsm.BATCH_SIZE, len(batch), **self._rlabels())
+                rec.observe(obsm.BATCH_OCCUPANCY,
+                            len(batch) / max(1, self.max_batch),
+                            **self._rlabels())
+                rec.gauge(obsm.QUEUE_DEPTH, len(self._queue),
+                          **self._rlabels())
         return batch
+
+    def _span(self, name: str, batch: List[VideoRequest], **args):
+        """A ``serve`` span of the current batch (``repro.obs.trace.span``:
+        on the profiler's clock always, in the recorder's trace when one
+        is attached), identified by ``batch_seq`` and its request ids."""
+        return span(name, getattr(self.recorder, "trace", None),
+                    cat="serve", batch_seq=self._batch_seq,
+                    request_ids=[r.request_id for r in batch], **args)
 
     # ------------------------------------------------------------ serving
     def observe_group_times(self, step_times) -> None:
@@ -907,22 +910,21 @@ class LPServingEngine:
             life = self._lifecycle.get(r.request_id)
             if life is not None:
                 life.setdefault("denoise_start_s", start_s)
-        ctx = jnp.concatenate([r.context for r in reqs], axis=0)
-        null_ctx = jnp.zeros_like(ctx)
-        guidance = jnp.float32(reqs[0].guidance)
-        keys = [jax.random.PRNGKey(r.seed) for r in reqs]
-        z_T = jnp.concatenate([
-            jax.random.normal(k, (1, *shape, self.cfg.latent_channels))
-            for k in keys
-        ], axis=0)
+        with self._span("batch.draw", reqs):
+            ctx = jnp.concatenate([r.context for r in reqs], axis=0)
+            null_ctx = jnp.zeros_like(ctx)
+            guidance = jnp.float32(reqs[0].guidance)
+            keys = [jax.random.PRNGKey(r.seed) for r in reqs]
+            z_T = jnp.concatenate([
+                jax.random.normal(k, (1, *shape, self.cfg.latent_channels))
+                for k in keys
+            ], axis=0)
 
         compiles0 = self._compiler.compiles
-        span = (rec.span("batch.denoise", cat="serve", size=len(reqs),
-                         latent_shape=shape, steps=self.num_steps,
-                         K=self.K, lp_impl=self.lp_impl)
-                if rec is not None else _NULL_CM)
         try:
-            with span:
+            with self._span("batch.denoise", reqs, size=len(reqs),
+                            latent_shape=shape, steps=self.num_steps,
+                            K=self.K, lp_impl=self.lp_impl):
                 z0 = lp_denoise(
                     None, z_T, self._sampler, self.num_steps, self.K,
                     self.r, self.cfg.patch_sizes, (1, 2, 3),
@@ -1078,16 +1080,17 @@ class LPServingEngine:
             while True:
                 try:
                     results = self._denoise_batch(reqs, snapshot)
-                    for res in results:
-                        res.restarts = restarts
-                        res.resumed_from_step = resumed_from
-                    self._finalize_requests(results)
-                    self._inflight = []
-                    out.extend(results)
-                    self._record_batch_wire(reqs[0].latent_shape,
-                                            len(reqs))
-                    if rec is not None:
-                        rec.inc(obsm.BATCHES, **self._rlabels())
+                    with self._span("batch.finalize", reqs):
+                        for res in results:
+                            res.restarts = restarts
+                            res.resumed_from_step = resumed_from
+                        self._finalize_requests(results)
+                        self._inflight = []
+                        out.extend(results)
+                        self._record_batch_wire(reqs[0].latent_shape,
+                                                len(reqs))
+                        if rec is not None:
+                            rec.inc(obsm.BATCHES, **self._rlabels())
                     break
                 except (DeviceFailure, ServingFault) as e:
                     restarts += 1

@@ -478,7 +478,7 @@ def test_flight_recorder_disabled_planes_noop():
     rec = FlightRecorder(trace=False, metrics=False)
     with rec.span("x"):
         pass
-    with rec.device_span("y"):
+    with rec.span("y", cat="denoise"):
         pass
     rec.instant("z")
     rec.inc(obsm.REQUESTS)
