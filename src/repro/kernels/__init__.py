@@ -1,7 +1,13 @@
 """Pallas TPU kernels for the compute hot-spots LP exercises:
 
-  flash_attention — the DiT/LM attention inner loop (MXU-tiled online
-                    softmax; the dominant FLOPs of every forward)
+  dit_attention   — the DiT's self- and cross-attention on a TPU (bf16
+                    MXU operands, f32 softmax state, no masks); the
+                    largest device time of every denoise step.  Where
+                    Pallas is interpreted or a GSPMD activation context
+                    is active, ``models/dit`` falls back to the chunked
+                    jnp scan (``models/attention.attention_chunked``)
+  flash_attention — the LM attention inner loop (MXU-tiled online
+                    softmax, GQA, causal/SWA masks)
   latent_blend    — LP's position-aware reconstruction (Eqs. 15-17) in a
                     single fused pass
   guidance_update — CFG combine + scheduler step epilogue, fused

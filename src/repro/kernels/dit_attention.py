@@ -1,0 +1,161 @@
+"""Pallas TPU kernel: bidirectional (DiT) flash attention, bf16 MXU operands.
+
+The DiT's self-attention (every video token sees every other) and its
+cross-attention (video tokens over the text context) are one operation:
+softmax(q k^T / sqrt(D)) v with no mask.  This kernel runs it as one
+online-softmax sweep per (row, head, q block):
+
+    q block   (bq, D)     bf16, scaled by 1/sqrt(D) before the kernel
+    k/v block (bkv, D)    bf16, swept in bkv_compute-wide chunks
+    m, l      (bq, 128)   f32 scratch, lane-replicated running max / sum
+    acc       (bq, D)     f32 scratch
+
+Both products take bf16 operands and accumulate in f32 on the MXU; the
+running max, sum and the output accumulator stay f32, and the
+probabilities are cast to bf16 only as the PV operand.
+
+Layout: q/k/v stay (B, S, H*D) as the projections produce them, and a
+block (bq, D) at column block h is head h, so no transpose surrounds the
+kernel.  A head size that is not a multiple of the 128 lanes is
+zero-padded to one (zero columns change no score, and the padded output
+columns are dropped).
+
+Lengths need not be block multiples (7,800 tokens at 17 frames): q and
+kv are zero-padded to their block multiples, padded query rows are
+dropped, and padded keys are masked by a static compare in the last kv
+block only, whose chunks that hold no real key are skipped outright.
+Every other block runs unmasked.
+"""
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+NEG_INF = -1.0e30     # finite: exp(NEG_INF - m) is 0 and never inf - inf
+LANES = 128
+NT = (((1,), (1,)), ((), ()))   # contract the last dims: q @ k^T
+NN = (((1,), (0,)), ((), ()))
+
+
+def _lanes(x, width):
+    """(bq, 128) lane-replicated -> (bq, width) by whole-vreg copies."""
+    return jnp.tile(x, (1, pl.cdiv(width, LANES)))[:, :width]
+
+
+def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
+            bkv: int, bkv_compute: int, num_kv_blocks: int, tail: int):
+    j = pl.program_id(3)
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def chunk(c, valid):
+        """Fold kv rows [c*bkv_compute, +bkv_compute) of the block into
+        the carry; ``valid`` < bkv_compute masks the rows past it."""
+        sl = pl.ds(c * bkv_compute, bkv_compute)
+        s = jax.lax.dot_general(q_ref[...], k_ref[sl, :], NT,
+                                preferred_element_type=jnp.float32)
+        if valid < bkv_compute:
+            col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(col < valid, s, NEG_INF)
+        m_prev, l_prev = m_ref[...], l_ref[...]
+        m_next = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_next, bkv_compute))
+        alpha = jnp.exp(m_prev - m_next)
+        l_ref[...] = alpha * l_prev + p.sum(axis=-1, keepdims=True)
+        m_ref[...] = m_next
+        pv = jax.lax.dot_general(p.astype(v_ref.dtype), v_ref[sl, :], NN,
+                                 preferred_element_type=jnp.float32)
+        acc_ref[...] = _lanes(alpha, acc_ref.shape[-1]) * acc_ref[...] + pv
+
+    def sweep(valid_rows):
+        for c in range(bkv // bkv_compute):
+            valid = valid_rows - c * bkv_compute
+            if valid > 0:                 # a chunk of padding only: skip
+                chunk(c, min(valid, bkv_compute))
+
+    if tail == bkv:
+        sweep(bkv)
+    else:
+        pl.when(j < num_kv_blocks - 1)(lambda: sweep(bkv))
+        pl.when(j == num_kv_blocks - 1)(lambda: sweep(tail))
+
+    @pl.when(j == num_kv_blocks - 1)
+    def _finish():
+        inv = 1.0 / l_ref[...]
+        o_ref[...] = (acc_ref[...] * _lanes(inv, acc_ref.shape[-1])
+                      ).astype(o_ref.dtype)
+
+
+def _pad_to(x, axis, multiple):
+    pad = -x.shape[axis] % multiple
+    if not pad:
+        return x
+    widths = [(0, 0)] * x.ndim
+    widths[axis] = (0, pad)
+    return jnp.pad(x, widths)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("block_q", "block_kv", "block_kv_compute", "interpret"),
+)
+def dit_attention(
+    q: jnp.ndarray,            # (B, Sq, H, D)
+    k: jnp.ndarray,            # (B, Skv, H, D)
+    v: jnp.ndarray,            # (B, Skv, H, D)
+    block_q: int = 1024,
+    block_kv: int = 2048,
+    block_kv_compute: int = 512,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """Unmasked softmax(q k^T / sqrt(D)) v, (B, Sq, H, D), for q, k and v
+    of one dtype (bf16 on the DiT's path)."""
+    B, Sq, H, D = q.shape
+    Skv = k.shape[1]
+    dt = q.dtype
+    scale = 1.0 / math.sqrt(D)
+    q = (q.astype(jnp.float32) * scale).astype(dt)
+    Dp = D + (-D % LANES)
+    # blocks no longer than the (lane-rounded) sequences they tile
+    bq = min(block_q, Sq + (-Sq % LANES))
+    bkv = min(block_kv, Skv + (-Skv % LANES))
+    bkc = math.gcd(min(block_kv_compute, bkv), bkv)
+    q, k, v = (_pad_to(x, 3, LANES) for x in (q, k, v))
+    q = _pad_to(q, 1, bq).reshape(B, -1, H * Dp)
+    k = _pad_to(k, 1, bkv).reshape(B, -1, H * Dp)
+    v = _pad_to(v, 1, bkv).reshape(B, -1, H * Dp)
+    nq, nk = q.shape[1] // bq, k.shape[1] // bkv
+    kernel = functools.partial(
+        _kernel, bkv=bkv, bkv_compute=bkc, num_kv_blocks=nk,
+        tail=Skv - (nk - 1) * bkv)
+    out = pl.pallas_call(
+        kernel,
+        grid=(B, H, nq, nk),
+        in_specs=[
+            pl.BlockSpec((None, bq, Dp), lambda b, h, i, j: (b, i, h)),
+            pl.BlockSpec((None, bkv, Dp), lambda b, h, i, j: (b, j, h)),
+            pl.BlockSpec((None, bkv, Dp), lambda b, h, i, j: (b, j, h)),
+        ],
+        out_specs=pl.BlockSpec((None, bq, Dp), lambda b, h, i, j: (b, i, h)),
+        out_shape=jax.ShapeDtypeStruct(q.shape, dt),
+        scratch_shapes=[
+            pltpu.VMEM((bq, LANES), jnp.float32),    # m
+            pltpu.VMEM((bq, LANES), jnp.float32),    # l
+            pltpu.VMEM((bq, Dp), jnp.float32),       # acc
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
+        interpret=interpret,
+        name="dit_flash_attention",
+    )(q, k, v)
+    return out.reshape(B, -1, H, Dp)[:, :Sq, :, :D]

@@ -9,6 +9,13 @@ tests and small models.
 
 Supports: causal, sliding-window (h2o-danube), bidirectional (encoders,
 DiT), GQA head grouping, and single-token decode against a KV cache.
+
+The DiT (``models/dit._attn``) takes another path where it can: on a
+backend that compiles Pallas (``kernels.ops.default_interpret()`` false)
+and outside a GSPMD activation context (``actctx.active()`` false, which
+only the sharded dry-run enters), its self- and cross-attention run in
+``kernels/dit_attention``, a bf16 flash kernel without masks.  Elsewhere
+(CPU, the dry-run) it falls back to ``attention_chunked`` here.
 """
 from __future__ import annotations
 

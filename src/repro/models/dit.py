@@ -23,6 +23,7 @@ import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.distributed import actctx
+from repro.kernels import ops as kernel_ops
 from .attention import attention_chunked
 from .layers import (
     dense,
@@ -141,7 +142,13 @@ def _axial_rope(q, grid, origin, head_dim, theta=10_000.0):
 
 def _attn(params, x, cfg, grid=None, origin=(0, 0, 0), context=None,
           kv_chunk: int = 4096):
-    """Bidirectional (DiT) self- or cross-attention."""
+    """Bidirectional (DiT) self- or cross-attention.
+
+    Where the backend compiles Pallas and no GSPMD activation context is
+    active (a ``pallas_call`` cannot be auto-partitioned), the fused
+    kernel ``kernels.ops.dit_attention`` runs it; otherwise (CPU, the
+    sharded dry-run) the chunked online-softmax scan does.
+    """
     B, S, _ = x.shape
     H, D = cfg.num_heads, cfg.head_dim
     src = x if context is None else context
@@ -157,9 +164,13 @@ def _attn(params, x, cfg, grid=None, origin=(0, 0, 0), context=None,
     q = actctx.shard_attn_q(q)
     k = actctx.shard_attn_kv(k)
     v = actctx.shard_attn_kv(v)
-    qp = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
-    kp = jnp.broadcast_to(jnp.arange(Skv)[None], (B, Skv))
-    out = attention_chunked(q, k, v, qp, kp, causal=False, kv_chunk=kv_chunk)
+    if kernel_ops.default_interpret() or actctx.active():
+        qp = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
+        kp = jnp.broadcast_to(jnp.arange(Skv)[None], (B, Skv))
+        out = attention_chunked(q, k, v, qp, kp, causal=False,
+                                kv_chunk=kv_chunk)
+    else:
+        out = kernel_ops.dit_attention(q, k, v)
     out = actctx.shard_attn_out(out.reshape(B, S, H * D))
     return dense(params["o"], out)
 

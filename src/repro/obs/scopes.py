@@ -19,7 +19,7 @@ from typing import Dict, List
 SCOPES = (
     "dit.embed",        # patchify, patch/text projections, time MLP
     "dit.adaln",        # modulation vectors, the norms, _modulate
-    "dit.self_attn",    # q/k/v/o, RoPE, attention_chunked, gated residual
+    "dit.self_attn",    # q/k/v/o, RoPE, the DiT flash kernel, gated residual
     "dit.cross_attn",   # cross-attention sub-block with its pre-norm
     "dit.ffn",          # the FFN and its gated residual
     "dit.blocks",       # the scan over blocks: per-block weight slices

@@ -1,5 +1,7 @@
 """Pallas kernel validation: shape/dtype sweeps vs pure-jnp oracles,
 interpret mode (CPU container; TPU is the lowering target)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,6 +10,8 @@ import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.kernels import ops, ref
+from repro.kernels.dit_attention import dit_attention
+from repro.models.attention import attention_dense
 
 
 def _mk_qkv(rng, B, Sq, Skv, H, KV, D, dtype):
@@ -182,6 +186,73 @@ def test_latent_blend_bit_identical_to_oracle(K, extent, patch, r, F, blk_f):
     want = ref.latent_blend_ref(preds, w, z, plan.starts, plan.window,
                                 plan.extent)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+
+
+# ----------------------------------------------------------- DiT attention
+# (rows, B, Sq, Skv, H, D, blocks): ``blocks`` None takes the kernel's own
+# (v5e-swept) sizes through ``ops``; rows > 0 vmaps a leading axis the way
+# the one-chip engine maps LP windows over the step
+@pytest.mark.parametrize("rows,B,Sq,Skv,H,D,blocks", [
+    (0, 2, 300, 300, 2, 128, (128, 256, 128)),    # padded tail, 1 kv block
+    (0, 1, 1000, 1000, 2, 128, (256, 256, 128)),  # tail chunk masked, one skipped
+    (0, 1, 300, 512, 2, 128, (128, 1024, 512)),   # cross-attention: S x 512
+    (0, 1, 260, 260, 12, 128, (128, 128, 128)),   # WAN2.1's 12 heads of 128
+    (2, 2, 300, 300, 2, 128, (128, 256, 128)),    # vmapped rows
+    (0, 1, 200, 260, 3, 32, None),                # head size padded to 128
+], ids=["self300", "self1000", "cross512", "h12d128", "vmap_rows", "d32"])
+def test_dit_attention(rows, B, Sq, Skv, H, D, blocks):
+    """The bf16 DiT kernel against f32 dense attention on the same bf16
+    inputs.  Tolerance: three bf16 roundings (the 1/sqrt(D)-scaled q, the
+    probabilities as the PV operand, the output), each at most the unit
+    roundoff 2^-9 relative, on an output that is a convex combination of
+    the values; so the error stays under 3 * 2^-9 * max|v|."""
+    lead = (rows,) if rows else ()
+    rng = np.random.default_rng(Sq + Skv + H)
+    q, k, v = (jnp.asarray(rng.normal(size=lead + (B, S, H, D)), jnp.bfloat16)
+               for S in (Sq, Skv, Skv))
+    if blocks is None:
+        fn = ops.dit_attention
+    else:
+        bq, bkv, bkc = blocks
+        fn = functools.partial(dit_attention, block_q=bq, block_kv=bkv,
+                               block_kv_compute=bkc, interpret=True)
+    out = jax.vmap(fn)(q, k, v) if rows else fn(q, k, v)
+    f32 = [x.reshape((-1,) + x.shape[-3:]).astype(jnp.float32)
+           for x in (q, k, v)]
+    n = f32[0].shape[0]
+    want = attention_dense(*f32, jnp.zeros((n, Sq), jnp.int32),
+                           jnp.zeros((n, Skv), jnp.int32), causal=False)
+    assert out.shape == q.shape and out.dtype == jnp.bfloat16
+    tol = 3 * 2.0 ** -9 * float(jnp.abs(v.astype(jnp.float32)).max())
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32).reshape(want.shape), np.asarray(want),
+        atol=tol, rtol=0)
+
+
+@pytest.mark.parametrize("tpu,sharded,kernel", [
+    (False, False, False),   # CPU: the chunked scan
+    (True, False, True),     # TPU: the kernel
+    (True, True, False),     # GSPMD context (dry-run): the chunked scan
+], ids=["cpu", "tpu", "tpu_gspmd"])
+def test_dit_attention_path(monkeypatch, tpu, sharded, kernel):
+    """``dit._attn`` runs the kernel exactly where the backend compiles
+    Pallas and no activation-sharding context is active."""
+    import contextlib
+
+    from repro.configs import get_config
+    from repro.distributed import actctx
+    from repro.models import dit
+
+    cfg = get_config("wan21-dit-1.3b").reduced()
+    monkeypatch.setattr(ops, "default_interpret", lambda: not tpu)
+    params = dit.init_params(jax.random.PRNGKey(0), cfg)
+    blk = jax.tree.map(lambda x: x[0], params["blocks"])["self_attn"]
+    x = jnp.zeros((1, 16, cfg.d_model), jnp.dtype(cfg.dtype))
+    ctx = actctx.batch_axes(("data",)) if sharded else contextlib.nullcontext()
+    with ctx:
+        jaxpr = jax.make_jaxpr(
+            lambda x: dit._attn(blk, x, cfg, grid=(1, 4, 4)))(x)
+    assert ("pallas_call" in str(jaxpr)) == kernel
 
 
 def test_kernels_interpret_only_off_tpu():

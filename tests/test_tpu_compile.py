@@ -25,6 +25,10 @@ from repro.kernels.wire_codec import dequant_blend, int8_quantize
 # HBM the v5e compiler reports as usable per chip (of its 16 GB); it
 # checks only a program's temporaries, so arguments are added by hand
 V5E_USABLE_HBM = 15.75e9
+# temporaries of the one-chip 17-frame step: 6.92 / 6.70 / 6.92 GB (T/H/W)
+# with attention as the chunked f32 einsum scan, 0.77 / 0.75 / 0.77 GB
+# with the DiT flash kernel, which keeps no score tile in HBM
+SMOKE_STEP_TEMP = 1.0e9
 
 # (latent T x H x W, K): the one-chip smoke's 17 frames at 480p and one
 # chip's share of the four-chip 81-frame plan
@@ -116,16 +120,35 @@ def test_int8_quantize_compiles_for_v5e(one_chip, latent_k, dim, batch):
         _sds((plan.window, F), jnp.float32, one_chip)).compile())
 
 
+@pytest.mark.parametrize("rows,sq,skv", [
+    (2, 7800, 7800), (2, 7540, 7540), (2, 7800, 512),   # one chip: K=2
+    (1, 18720, 18720), (1, 17472, 17472), (1, 15750, 15750),  # a K=4 window
+], ids=["17f_TW", "17f_H", "17f_cross", "81f_T", "81f_H", "81f_W"])
+def test_dit_attention_compiles_for_v5e(one_chip, rows, sq, skv):
+    """The DiT flash kernel at WAN2.1-1.3B's 12 heads of 128 on the
+    cells' window lengths (the CFG pair is the batch, LP windows a
+    vmapped axis), with the block sizes ``ops`` picks."""
+    from repro.kernels import ops
+
+    fn = jax.jit(jax.vmap(lambda q, k, v: ops.dit_attention(
+        q, k, v, interpret=False)))
+    q = _sds((rows, 2, sq, 12, 128), jnp.bfloat16, one_chip)
+    kv = _sds((rows, 2, skv, 12, 128), jnp.bfloat16, one_chip)
+    _assert_kernel(fn.lower(q, kv, kv).compile())
+
+
 @pytest.mark.parametrize("dim", [0, 1, 2])
 def test_smoke_guided_step_fits_v5e(one_chip, dim, monkeypatch):
     """The one-chip engine's compiled LP step at WAN2.1-1.3B's published
     width: K=2 windows x CFG pair on the 17-frame latent, batch 1, with
-    the stitch kernel compiled in.  Temporaries plus arguments (the
-    parameters) must fit the chip."""
+    the stitch kernel compiled in and both attention sub-blocks in the
+    DiT flash kernel.  Temporaries plus arguments (the parameters) must
+    fit the chip, and the temporaries stay under the kernel's bound."""
     from repro import models
     from repro.configs import get_config
     from repro.kernels import ops
     from repro.models import dit
+    from repro.obs.scopes import scope_map
     from repro.serving.engine import LPServingEngine
 
     # this process's backend is the CPU; steer the step onto the chip's
@@ -147,8 +170,13 @@ def test_smoke_guided_step_fits_v5e(one_chip, dim, monkeypatch):
     step = comp.step_fn(dim, z, 1, np.float32(0.0), extras)
     compiled = step.lower(z, scalar, scalar, extras).compile()
     _assert_kernel(compiled)
+    scopes = scope_map(compiled.as_text())
+    assert {scope for name, scope in scopes.items()
+            if name.startswith("dit_flash_attention")} == {
+        "dit.self_attn", "dit.cross_attn"}
     mem = compiled.memory_analysis()
     total = mem.temp_size_in_bytes + mem.argument_size_in_bytes
     assert mem.argument_size_in_bytes > 4.4e9   # the parameters are args
     assert total < V5E_USABLE_HBM, (mem.temp_size_in_bytes,
                                     mem.argument_size_in_bytes)
+    assert mem.temp_size_in_bytes < SMOKE_STEP_TEMP, mem.temp_size_in_bytes
