@@ -8,6 +8,13 @@ every other engine option at its default), with weights made here from
 ``--seed``.  One client sends requests back to back: submit, run, block
 on the returned latent, next.  The window closes after the first request
 that ends past ``--seconds``; every request in it is whole.
+
+What belongs to the configuration's architecture comes from its module
+(``Cell.model``, ``bench/models/<model>.py``): the program's forward and
+config, the weights, a request's conditioning, the extras of a replayed
+step, the reference's prediction and the FLOPs of a step.  This file
+holds the engine, the request stream, the window, the check and the
+result line, which every architecture shares.
 """
 from __future__ import annotations
 
@@ -18,6 +25,7 @@ import sys
 import time
 from contextlib import contextmanager, nullcontext
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
@@ -60,6 +68,7 @@ class Cell:
     conf: dict
     traffic: dict
     seed: int
+    model: ModuleType
 
     @property
     def arch(self) -> dict:
@@ -83,26 +92,9 @@ def load_cell(name: str, seed: int, root: Path = spec.ROOT,
               base: Path = spec.BENCH) -> Cell:
     bench = spec.benchmark(root)
     c = spec.cell(bench, name)
-    return Cell(name, c["chips"], spec.config(c["config"], base),
-                spec.traffic(c["traffic"], base), seed)
-
-
-def program_config(cell: Cell):
-    """The program's ArchConfig for the configuration, checked against
-    the file: every width the file states is the one that runs."""
-    from repro.configs import get_config
-
-    a = cell.arch
-    cfg = dataclasses.replace(
-        get_config(cell.conf["model"]),
-        num_layers=a["num_layers"], d_model=a["d_model"],
-        num_heads=a["num_heads"], num_kv_heads=a["num_heads"],
-        head_dim=a["head_dim"], d_ff=a["d_ff"],
-        patch_sizes=tuple(a["patch_sizes"]),
-        latent_channels=a["latent_channels"],
-        context_len=a["context_len"], context_dim=a["context_dim"],
-        time_embed_dim=a["time_embed_dim"], dtype=a["dtype"])
-    return cfg
+    conf = spec.config(c["config"], base)
+    return Cell(name, c["chips"], conf, spec.traffic(c["traffic"], base),
+                seed, spec.model(conf["model"], base))
 
 
 class Served:
@@ -110,14 +102,10 @@ class Served:
 
     def __init__(self, cell: Cell, devices):
         import jax
-        import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec, \
             SingleDeviceSharding
 
-        from repro.models import dit
         from repro.serving.engine import LPServingEngine
-
-        from . import weights
 
         self.cell = cell
         tr = cell.traffic
@@ -132,32 +120,29 @@ class Served:
         else:
             sharding = SingleDeviceSharding(devices[0])
             self.devices = [devices[0]]
-        self.cfg = program_config(cell)
+        forward, self.cfg = cell.model.program(cell.conf)
         wkey = int(cell.rng("weights").integers(0, 2 ** 31 - 1))
-        self.params = weights.make_params(jax.random.PRNGKey(wkey),
-                                          cell.arch, sharding)
+        self.params = cell.model.make_params(jax.random.PRNGKey(wkey),
+                                             cell.arch, sharding)
         self.engine = LPServingEngine(
-            dit.forward, self.params, self.cfg,
+            forward, self.params, self.cfg,
             num_partitions=tr["partitions"], overlap_ratio=tr["overlap"],
             num_steps=cell.steps, max_batch=tr["max_batch"],
             lp_impl=tr.get("lp_impl", "auto"), mesh=self.mesh)
-        a = cell.arch
-        shape = (1, a["context_len"], a["context_dim"])
-        # the text encoder's output stand-in: unit-scale noise times 0.02
-        self._context = jax.jit(
-            lambda k: jax.random.normal(k, shape, jnp.float32) * 0.02)
         self._req_rng = cell.rng("requests")
         self.replay_compiles = 0
 
     def request(self, rid: int):
-        """The next request of the stream: its own noise seed and text."""
+        """The next request of the stream: its own noise seed and
+        conditioning."""
         import jax
 
         from repro.serving.engine import VideoRequest
 
         noise_seed, text_seed = (int(x) for x in
                                  self._req_rng.integers(0, 2 ** 31 - 1, 2))
-        ctx = self._context(jax.random.PRNGKey(text_seed))
+        ctx = self.cell.model.context(self.cell.arch,
+                                      jax.random.PRNGKey(text_seed))
         return VideoRequest(request_id=rid, context=ctx,
                             latent_shape=self.cell.latent, seed=noise_seed,
                             guidance=float(self.cell.traffic["guidance"]))
@@ -187,9 +172,7 @@ class Served:
         sampler = eng._sampler
         dims = usable_dims(req.latent_shape, self.cfg.patch_sizes, eng.K)
         sc, t = sampler.step_scalars(i), np.float32(sampler.timestep(i))
-        ctx = req.context
-        extras = (eng._step_params(), ctx, jnp.zeros_like(ctx),
-                  jnp.float32(req.guidance))
+        extras = self.cell.model.step_extras(eng, req)
         x = jnp.asarray(np.asarray(z[None], np.float32))
         if i > 1:
             # later steps take the previous step's output: committed to
@@ -220,6 +203,14 @@ def rel(a: np.ndarray, b: np.ndarray, scale: np.ndarray) -> float:
     return float(np.linalg.norm(a - b) / np.linalg.norm(scale))
 
 
+def request_context(req):
+    """The request's conditioning as the reference takes it: each array
+    on the host, float32, without the batch dim."""
+    import jax
+
+    return jax.tree.map(lambda c: np.asarray(c, np.float32)[0], req.context)
+
+
 def check_request(served: Served, req, latent: np.ndarray,
                   ref: "reference.Reference"):
     """The numbers that decide ``correct`` for one served request, and
@@ -231,7 +222,7 @@ def check_request(served: Served, req, latent: np.ndarray,
     steps the window ran, each from the reference's latent before it,
     against the reference's step, as a share of that step's move."""
     tr = served.cell.traffic
-    ctx = np.asarray(req.context, np.float32)[0]
+    ctx = request_context(req)
     z_T = served.noise(req)
     traj = ref.trajectory(z_T, served.cell.steps, tr["partitions"],
                           tr["overlap"], ctx, req.guidance)
@@ -364,7 +355,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool, t0: float,
     n_check = min(cell.traffic.get("check_requests", 1), len(finished))
     picked = sorted(sample_rng.choice(finished, n_check, replace=False)) \
         if finished else []
-    ref = reference.Reference(cell.arch, served.params, served.mesh,
+    ref = reference.Reference(cell.model, cell.arch, served.params,
+                              served.mesh,
                               None if served.mesh is None else "data")
     readings: Dict[str, float] = {}
     for j in picked:
@@ -394,6 +386,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, t0: float,
 
     rec = {
         "arch": cell.arch, "latent": cell.latent, "chips": cell.chips,
+        "step_flops": cell.model.step_flops(cell.arch, cell.latent),
         "peaks": pk, "step_s": step_s, "setup_s": setup_s,
         "steps": steps, "requests": done, "window_s": window_s,
         "memory": memory, "stitch": stitch_calls(cell, done),

@@ -3,7 +3,15 @@
 ``BENCHMARK.json`` (checkout root) names each cell's configuration and
 traffic mix; the files live under ``bench/``:
 
-* ``configs/<config>.json``: the model configuration as it is run;
+* ``configs/<config>.json``: the model configuration as it is run; its
+  ``model`` names the architecture;
+* ``models/<model>.py``: everything that belongs to one architecture:
+  ``program(conf) -> (forward, cfg)``, ``make_params(key, arch,
+  sharding)``, ``context(arch, key)``, ``step_extras(engine, req)``, the
+  reference's ``window_fn(arch, quant, mesh, axis)`` and ``guide(pred,
+  guidance)``, and ``step_flops(arch, latent)``.  The harness, the
+  reference's windows, stitch and Euler step, and the metric readers
+  hold nothing of any one architecture;
 * ``workloads/<traffic>.json``: the traffic mix (LP degree, overlap,
   steps per request, batch, guidance, loop);
 * ``limits/<cell>.json``: the limits of the numbers that decide
@@ -68,10 +76,19 @@ def freeze(arch: dict) -> tuple:
                         for k, v in arch.items()))
 
 
-def reader(metric: str, base: Path = BENCH):
-    path = base / "metrics" / f"{metric}.py"
+def _load(kind: str, name: str, base: Path):
+    path = base / kind / f"{name}.py"
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + metric.replace(".", "_").replace("-", "_"), path)
+        f"bench_{kind}_" + name.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(metric: str, base: Path = BENCH):
+    return _load("metrics", metric, base).read
+
+
+def model(name: str, base: Path = BENCH):
+    """The architecture module ``models/<name>.py``."""
+    return _load("models", name, base)

@@ -56,7 +56,7 @@ def main(argv=None) -> int:
     import jax
     import numpy as np
 
-    from benchlib import harness, reference, weights
+    from benchlib import harness, reference
 
     seeds = [int(s) for s in args.seeds.split(",")]
     devices = jax.devices()
@@ -83,15 +83,16 @@ def main(argv=None) -> int:
         ref = ctrl = None
         gc.collect()
         wkey = int(cell.rng("weights").integers(0, 2 ** 31 - 1))
-        served.params = weights.make_params(jax.random.PRNGKey(wkey),
-                                            cell.arch, sharding)
+        served.params = cell.model.make_params(jax.random.PRNGKey(wkey),
+                                               cell.arch, sharding)
         served.engine.params = served.params
         served._req_rng = cell.rng("requests")
         req = served.request(0)
         t = time.perf_counter()
         latent = np.asarray(served.serve(req), np.float64)[0]
         serve_s = time.perf_counter() - t
-        ref = reference.Reference(cell.arch, served.params, served.mesh, axis)
+        ref = reference.Reference(cell.model, cell.arch, served.params,
+                                  served.mesh, axis)
         t = time.perf_counter()
         got, traj = harness.check_request(served, req, latent, ref)
         line = {"workload": args.workload, "seed": seed, "program": got,
@@ -99,10 +100,10 @@ def main(argv=None) -> int:
                 "serve_s": serve_s,
                 "check_s": time.perf_counter() - t}
         if args.control:
-            ctrl = reference.Reference(cell.arch, served.params, served.mesh,
-                                       axis, quant="fp8")
+            ctrl = reference.Reference(cell.model, cell.arch, served.params,
+                                       served.mesh, axis, quant="fp8")
             t = time.perf_counter()
-            ctx = np.asarray(req.context, np.float32)[0]
+            ctx = harness.request_context(req)
             line["control"] = control_readings(
                 ctrl, traj, traj[0], cell.steps, tr["partitions"],
                 tr["overlap"], ctx, req.guidance)

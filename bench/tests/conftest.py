@@ -22,8 +22,8 @@ TINY_LIMITS = {"served_err": {"limit": 1e-3}, "step_err": {"limit": 1e-3}}
 
 
 def make_tree(tmp: Path) -> Path:
-    """A benchmark tree (BENCHMARK.json, configs, workloads, metrics,
-    limits) holding the tiny cells ``tiny-lp2`` (one device) and
+    """A benchmark tree (BENCHMARK.json, configs, models, workloads,
+    metrics, limits) holding the tiny cells ``tiny-lp2`` (one device) and
     ``tiny-lp4`` (four)."""
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     bench["workloads"] = [
@@ -37,6 +37,8 @@ def make_tree(tmp: Path) -> Path:
             m["workloads"] = ["tiny-lp2", "tiny-lp4"]
     (tmp / "BENCHMARK.json").write_text(json.dumps(bench))
     shutil.copytree(DATA / "configs", tmp / "configs")
+    shutil.copytree(BENCH / "models", tmp / "models",
+                    ignore=shutil.ignore_patterns("__pycache__"))
     shutil.copytree(BENCH / "workloads", tmp / "workloads")
     shutil.copytree(BENCH / "metrics", tmp / "metrics")
     (tmp / "limits").mkdir()

@@ -1,12 +1,13 @@
 """FLOP and byte counts against hand counts at the published widths."""
 import json
 
-from benchlib import flops
+from benchlib import flops, spec
 
 from conftest import BENCH
 
 ARCH = json.loads((BENCH / "configs" / "wan21-1.3b-480p-17f.json")
                   .read_text())["arch"]
+WAN = spec.model("wan21-dit-1.3b")
 
 
 def test_tokens_of_the_two_latents():
@@ -26,13 +27,13 @@ def test_dit_forward_flops_by_hand_17f():
     outside = (2 * s * 64 * d + 2 * ctx * 4096 * d
                + 2 * 256 * 1536 + 2 * 1536 * 1536
                + 2 * 1536 * 2 * d + 2 * s * d * 64)
-    assert flops.dit_forward_flops(ARCH, (5, 60, 104)) == 30 * block + outside
+    assert WAN.forward_flops(ARCH, (5, 60, 104)) == 30 * block + outside
 
 
 def test_guided_step_is_two_forwards_and_matches_the_estimates():
-    f17 = flops.guided_step_flops(ARCH, (5, 60, 104))
-    f81 = flops.guided_step_flops(ARCH, (21, 60, 104))
-    assert f17 == 2 * flops.dit_forward_flops(ARCH, (5, 60, 104))
+    f17 = WAN.step_flops(ARCH, (5, 60, 104))
+    f81 = WAN.step_flops(ARCH, (21, 60, 104))
+    assert f17 == 2 * WAN.forward_flops(ARCH, (5, 60, 104))
     # 7.7e13 and 6.2e14: the published-width estimates of the two cells
     assert 7.4e13 < f17 < 7.8e13
     assert 6.0e14 < f81 < 6.4e14

@@ -8,12 +8,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchlib import harness, reference
+from benchlib import harness, reference, spec
 
 from conftest import DATA
 from harness_run import run_cell
 
 TINY = json.loads((DATA / "configs" / "tiny.json").read_text())["arch"]
+WAN = spec.model("wan21-dit-1.3b")
 
 
 @pytest.mark.parametrize("latent,k", [((5, 60, 104), 2), ((21, 60, 104), 4),
@@ -45,7 +46,7 @@ def test_reference_matches_the_engine_at_small_size(tree):
     served = harness.Served(cell, jax.devices())
     req = served.request(0)
     latent = np.asarray(served.serve(req), np.float64)[0]
-    ref = reference.Reference(cell.arch, served.params)
+    ref = reference.Reference(cell.model, cell.arch, served.params)
     got, traj = harness.check_request(served, req, latent, ref)
     # float32 program, float32 reference: summation order only
     assert got["served_err"] < 1e-4 and got["step_err"] < 1e-4
@@ -59,7 +60,7 @@ def test_the_control_is_judged_not_correct(tree, monkeypatch):
     from repro.models import dit
 
     def fp8_forward(params, z, t, ctx, cfg, **kw):
-        rows = [reference.velocity(params, z[b], t[b], ctx[b], TINY, "fp8")
+        rows = [WAN.velocity(params, z[b], t[b], ctx[b], TINY, "fp8")
                 for b in range(z.shape[0])]
         return jnp.stack(rows).astype(z.dtype)
 

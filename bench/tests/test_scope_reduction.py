@@ -108,7 +108,10 @@ def seed_rec(trace, summary, chips: int) -> dict:
     """A result record around a recorded trace, with fixed host-side
     numbers, for the metrics' readers."""
     conf = spec.config("wan21-1.3b-480p-17f")
-    return {"arch": conf["arch"], "latent": tuple(conf["latent"]),
+    latent = tuple(conf["latent"])
+    return {"arch": conf["arch"], "latent": latent,
+            "step_flops": spec.model(conf["model"]).step_flops(
+                conf["arch"], latent),
             "chips": chips, "peaks": peaks("TPU v5 lite"), "step_s": 2.4,
             "setup_s": 25.0, "steps": 9, "requests": 3, "window_s": 21.6,
             "memory": [{"peak_bytes_in_use": 11e9,
