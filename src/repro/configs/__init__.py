@@ -23,9 +23,11 @@ _MODULES = {
     "granite-moe-3b-a800m": "granite_moe_3b_a800m",
     "llama4-maverick-400b-a17b": "llama4_maverick_400b_a17b",
     "wan21-dit-1.3b": "wan21_dit_1p3b",
+    "hunyuanvideo-dit": "hunyuan_video",
 }
 
-ASSIGNED_ARCHS = tuple(k for k in _MODULES if k != "wan21-dit-1.3b")
+VDM_ARCHS = ("wan21-dit-1.3b", "hunyuanvideo-dit")
+ASSIGNED_ARCHS = tuple(k for k in _MODULES if k not in VDM_ARCHS)
 ALL_ARCHS = tuple(_MODULES)
 
 # archs with sub-quadratic attention paths — the only ones long_500k runs on

@@ -66,6 +66,18 @@ class ArchConfig:
     context_dim: int = 0             # cross-attention context width
     time_embed_dim: int = 256
 
+    # MMDiT (HunyuanVideo): a token refiner over the text, then
+    # double-stream blocks (video and text with their own weights, one
+    # joint attention), then single-stream blocks over [video; text].
+    # ``guidance_embed`` feeds the guidance scale to the network: one
+    # forward per step and no CFG pair (``diffusion/pipeline``).
+    double_layers: int = 0
+    single_layers: int = 0
+    refiner_layers: int = 0
+    pooled_dim: int = 0              # pooled text vector (CLIP-L) width
+    rope_axes: Tuple[int, ...] = ()  # RoPE widths over (t, h, w) per head
+    guidance_embed: bool = False
+
     # numerics
     dtype: str = "bfloat16"
     norm_eps: float = 1e-5
@@ -132,6 +144,10 @@ class ArchConfig:
                 time_embed_dim=32,
                 num_layers=2,
             )
+        if self.double_layers:
+            changes.update(double_layers=1, single_layers=2,
+                           num_layers=3, refiner_layers=1, pooled_dim=32,
+                           rope_axes=(8, 12, 12))
         return dataclasses.replace(self, name=self.name + "-reduced", **changes)
 
 

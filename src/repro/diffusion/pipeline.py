@@ -41,14 +41,30 @@ def make_guided_step_denoiser(dit_forward, cfg_model,
     """Fully-traced guided denoiser for the compiled LP step cache.
 
     Unlike :func:`make_guided_denoiser`, neither the parameters nor the
-    conditioning are closed over: ``(window, t, params, context,
-    null_context, guidance)`` are all traced arguments, so one compiled
-    step serves every batch of the same geometry — the serving engine
-    builds this once per engine, not once per batch.  Parameters that a
-    jitted closure captured would be embedded in the program as
+    conditioning are closed over: they are traced arguments, so one
+    compiled step serves every batch of the same geometry — the serving
+    engine builds this once per engine, not once per batch.  Parameters
+    that a jitted closure captured would be embedded in the program as
     constants (4.49 GB at WAN2.1-1.3B's published width).  ``t`` is a
     traced f32 scalar (the LP step protocol).
+
+    The architecture decides the form.  With ``cfg_model.guidance_embed``
+    the guidance scale is an input of the network: ``guided(window, t,
+    params, cond, guidance)`` is one forward over the window's B rows,
+    ``cond`` any pytree the forward reads.  Otherwise classifier-free
+    guidance: ``guided(window, t, params, context, null_context,
+    guidance)`` runs the conditional and unconditional pair as one batch
+    of 2B rows and combines them.
     """
+    if cfg_model.guidance_embed:
+        def embedded(window, t, params, cond, guidance=None):
+            g = guidance_default if guidance is None else guidance
+            b = window.shape[0]
+            return dit_forward(params, window, jnp.full((b,), t, jnp.float32),
+                               cond, cfg_model,
+                               jnp.full((b,), g, jnp.float32))
+
+        return embedded
 
     def guided(window, t, params, context, null_context, guidance=None):
         g = guidance_default if guidance is None else guidance

@@ -4,9 +4,11 @@
       --partitions 2 --overlap 0.5 [--latent 5x60x104] [--max-batch 4] \
       [--lp-impl auto] [--wire-codec int8-residual] [--reduced]
 
-The model is WAN2.1-1.3B at its published width (30 blocks, d 1536,
-bf16) with random weights from a fixed seed; ``--reduced`` swaps in the
-2-block, 128-wide f32 stand-in for CPU smoke runs.  ``--latent`` is the
+``--arch`` names the video denoiser by configuration
+(``repro.models.FORWARDS``): WAN2.1-1.3B (default; 30 blocks, d 1536,
+bf16) or HunyuanVideo's MMDiT (``hunyuanvideo-dit``), at its published
+width with random weights from a fixed seed; ``--reduced`` swaps in the
+small f32 stand-in for CPU smoke runs.  ``--latent`` is the
 latent each request denoises (T x H x W; 5x60x104 is 17 frames at 480p).
 
 Step policy (docs/step_policy.md): ``--codec-schedule auto`` lets the
@@ -31,7 +33,7 @@ from repro import models
 from repro.comm.codecs import CODEC_NAMES
 from repro.configs import get_config
 from repro.launch.compile_cache import enable_compile_cache
-from repro.models import dit, frontends
+from repro.models import frontends
 from repro.serving.engine import LPServingEngine, VideoRequest
 
 
@@ -47,12 +49,14 @@ def parse_latent(spec: str) -> Tuple[int, int, int]:
     return dims
 
 
-def load_model(reduced: bool = False, mesh=None):
-    """``(cfg, params)``: WAN2.1-1.3B at published width (or its reduced
-    stand-in), random weights from seed 0.  Initialized in one compiled
-    program, straight onto ``mesh`` (replicated) when one is given, so
-    the parameters never sit on one device before being copied out."""
-    cfg = get_config("wan21-dit-1.3b")
+def load_model(reduced: bool = False, mesh=None,
+               arch: str = "wan21-dit-1.3b"):
+    """``(cfg, params)``: the denoiser ``arch`` at published width (or
+    its reduced stand-in), random weights from seed 0.  Initialized in
+    one compiled program, straight onto ``mesh`` (replicated) when one
+    is given, so the parameters never sit on one device before being
+    copied out."""
+    cfg = get_config(arch)
     if reduced:
         cfg = cfg.reduced()
     model = models.build(cfg)
@@ -68,6 +72,9 @@ def load_model(reduced: bool = False, mesh=None):
 
 def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
+    ap.add_argument("--arch", default="wan21-dit-1.3b",
+                    choices=sorted(models.FORWARDS),
+                    help="the video denoiser, by configuration name")
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--steps", type=int, default=6)
     ap.add_argument("--partitions", type=int, default=2)
@@ -154,8 +161,8 @@ def build_engine(args, recorder=None):
                 f"--mesh {args.mesh}: LP axis {m} != --partitions "
                 f"{args.partitions}")
         mesh = make_hybrid_mesh(m, t)
-    cfg, params = load_model(args.reduced, mesh)
-    engine = LPServingEngine(dit.forward, params, cfg,
+    cfg, params = load_model(args.reduced, mesh, args.arch)
+    engine = LPServingEngine(models.FORWARDS[args.arch], params, cfg,
                              num_partitions=args.partitions,
                              overlap_ratio=args.overlap,
                              num_steps=args.steps,
@@ -179,7 +186,7 @@ def make_requests(args, cfg) -> List[VideoRequest]:
     return [
         VideoRequest(
             request_id=i,
-            context=frontends.text_context(jax.random.PRNGKey(i), 1, cfg),
+            context=frontends.conditioning(jax.random.PRNGKey(i), 1, cfg),
             latent_shape=tuple(args.latent),
             seed=i,
         )

@@ -16,8 +16,14 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
-from . import dit, encdec, frontends, transformer
+from . import dit, encdec, frontends, hunyuan_video, transformer
 from .transformer import cross_entropy_chunked
+
+# The video denoisers, by configuration name (``repro.configs``; a
+# ``reduced()`` stand-in runs its source's): modules with
+# ``init_params(key, cfg)`` and ``forward(params, z, t, cond, cfg, ...)``.
+DENOISERS = {"wan21-dit-1.3b": dit, "hunyuanvideo-dit": hunyuan_video}
+FORWARDS = {name: mod.forward for name, mod in DENOISERS.items()}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -81,12 +87,15 @@ def build(cfg: ArchConfig) -> Model:
             ),
         )
     if fam == "vdm":
+        mod = DENOISERS[cfg.name.removesuffix("-reduced")]
+        extra = ((lambda batch: (batch["guidance"],)) if cfg.guidance_embed
+                 else (lambda batch: ()))
         return Model(
             cfg=cfg,
-            init=lambda key: dit.init_params(key, cfg),
+            init=lambda key: mod.init_params(key, cfg),
             forward=lambda p, batch, **kw: (
-                dit.forward(p, batch["latent"], batch["t"], batch["context"],
-                            cfg, **kw),
+                mod.forward(p, batch["latent"], batch["t"],
+                            batch["context"], cfg, *extra(batch), **kw),
                 jnp.float32(0.0),
             ),
         )

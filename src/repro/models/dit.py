@@ -142,13 +142,8 @@ def _axial_rope(q, grid, origin, head_dim, theta=10_000.0):
 
 def _attn(params, x, cfg, grid=None, origin=(0, 0, 0), context=None,
           kv_chunk: int = 4096):
-    """Bidirectional (DiT) self- or cross-attention.
-
-    Where the backend compiles Pallas and no GSPMD activation context is
-    active (a ``pallas_call`` cannot be auto-partitioned), the fused
-    kernel ``kernels.ops.dit_attention`` runs it; otherwise (CPU, the
-    sharded dry-run) the chunked online-softmax scan does.
-    """
+    """Bidirectional (DiT) self- or cross-attention, through
+    :func:`attend`."""
     B, S, _ = x.shape
     H, D = cfg.num_heads, cfg.head_dim
     src = x if context is None else context
@@ -164,15 +159,24 @@ def _attn(params, x, cfg, grid=None, origin=(0, 0, 0), context=None,
     q = actctx.shard_attn_q(q)
     k = actctx.shard_attn_kv(k)
     v = actctx.shard_attn_kv(v)
-    if kernel_ops.default_interpret() or actctx.active():
-        qp = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
-        kp = jnp.broadcast_to(jnp.arange(Skv)[None], (B, Skv))
-        out = attention_chunked(q, k, v, qp, kp, causal=False,
-                                kv_chunk=kv_chunk)
-    else:
-        out = kernel_ops.dit_attention(q, k, v)
+    out = attend(q, k, v, kv_chunk)
     out = actctx.shard_attn_out(out.reshape(B, S, H * D))
     return dense(params["o"], out)
+
+
+def attend(q, k, v, kv_chunk: int = 4096):
+    """Unmasked attention, (B, S, H, D) x (B, Skv, H, D): the fused
+    kernel ``kernels.ops.dit_attention`` where the backend compiles
+    Pallas and no GSPMD activation context is active (a ``pallas_call``
+    cannot be auto-partitioned), else the chunked online-softmax scan."""
+    if kernel_ops.default_interpret() or actctx.active():
+        B, S = q.shape[:2]
+        Skv = k.shape[1]
+        qp = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
+        kp = jnp.broadcast_to(jnp.arange(Skv)[None], (B, Skv))
+        return attention_chunked(q, k, v, qp, kp, causal=False,
+                                 kv_chunk=kv_chunk)
+    return kernel_ops.dit_attention(q, k, v)
 
 
 def _modulate(x, shift, scale):

@@ -32,3 +32,18 @@ def text_context(key, batch: int, cfg: ArchConfig) -> jnp.ndarray:
     return (
         jax.random.normal(key, (batch, cfg.context_len, cfg.context_dim)) * 0.02
     ).astype(jnp.float32)
+
+
+def conditioning(key, batch: int, cfg: ArchConfig):
+    """A request's conditioning stand-in for ``cfg``'s denoiser: WAN's
+    text context, or for a model with a pooled text vector (HunyuanVideo)
+    ``{"text": (B, L_ctx, ctx_dim), "pooled": (B, pooled_dim)}`` of
+    unit-scale noise, the scale of a normalized encoder output."""
+    if not cfg.pooled_dim:
+        return text_context(key, batch, cfg)
+    kt, kp = jax.random.split(key)
+    return {
+        "text": jax.random.normal(
+            kt, (batch, cfg.context_len, cfg.context_dim), jnp.float32),
+        "pooled": jax.random.normal(kp, (batch, cfg.pooled_dim), jnp.float32),
+    }

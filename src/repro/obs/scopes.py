@@ -25,6 +25,13 @@ SCOPES = (
     "dit.blocks",       # the scan over blocks: per-block weight slices
     "dit.head",         # final adaLN, norm, head, unpatchify
     "dit.cfg",          # the CFG pair's inputs and the guided combine
+    "mmdit.embed",      # MMDiT: patchify, video and vector embedders, RoPE
+    "mmdit.refiner",    # the token refiner over the text
+    "mmdit.double_attn",  # double-stream modulation, q/k/v, joint attention
+    "mmdit.double_mlp",   # double-stream gated MLPs
+    "mmdit.single",     # single-stream block: fused projection, attention
+    "mmdit.head",       # final adaLN, norm, head, unpatchify
+    "mmdit.blocks",     # the scans over both kinds of block
     "lp.window",        # window slicing (the rotating partition)
     "lp.stitch",        # weighting, normalising, reassembly, latent_blend
     "lp.halo",          # the collectives: halo slabs, core gather, psum

@@ -133,7 +133,10 @@ class QueueFull(RuntimeError):
 @dataclasses.dataclass
 class VideoRequest:
     request_id: int
-    context: jnp.ndarray          # (1, L_ctx, ctx_dim) encoded prompt
+    # the encoded prompt, with a batch dim of 1: WAN's (1, L_ctx, ctx_dim)
+    # text context, or any pytree the denoiser reads (HunyuanVideo:
+    # {"text": (1, L, ctx_dim), "pooled": (1, pooled_dim)})
+    context: Any
     latent_shape: Tuple[int, int, int]   # (T_lat, H_lat, W_lat)
     seed: int = 0
     guidance: float = 5.0
@@ -809,6 +812,15 @@ class LPServingEngine:
                         **self._rlabels())
             self._replan_schedule()
 
+    def _conditioning(self, ctx) -> Tuple:
+        """The batch's conditioning as the guided step takes it, between
+        the parameters and the guidance scale: the conditioning alone for
+        a network that embeds the guidance, else the CFG pair's
+        conditional and all-zero unconditional contexts."""
+        if self.cfg.guidance_embed:
+            return (ctx,)
+        return (ctx, jnp.zeros_like(ctx))
+
     # ------------------------------------------------------ fault drills
     def _activate_corrupt(self) -> None:
         """Swap the live wire codec for its NaN-decoding twin for ONE
@@ -911,8 +923,9 @@ class LPServingEngine:
             if life is not None:
                 life.setdefault("denoise_start_s", start_s)
         with self._span("batch.draw", reqs):
-            ctx = jnp.concatenate([r.context for r in reqs], axis=0)
-            null_ctx = jnp.zeros_like(ctx)
+            ctx = jax.tree.map(lambda *c: jnp.concatenate(c, axis=0),
+                               *[r.context for r in reqs])
+            cond = self._conditioning(ctx)
             guidance = jnp.float32(reqs[0].guidance)
             keys = [jax.random.PRNGKey(r.seed) for r in reqs]
             z_T = jnp.concatenate([
@@ -931,8 +944,7 @@ class LPServingEngine:
                     uniform=self.uniform,
                     # read per dispatch: an eviction in the step hook
                     # moves the mesh, and the parameters with it
-                    extras=lambda: (self._step_params(), ctx, null_ctx,
-                                    guidance),
+                    extras=lambda: (self._step_params(), *cond, guidance),
                     compiler=self._compiler,
                     step_hook=self._step_hook(), snapshot=snapshot,
                     recorder=rec,

@@ -56,6 +56,23 @@ def test_serve_reduced_end_to_end(capsys, no_cache_dir):
         ["1", "2", "2"]
 
 
+def test_serve_reduced_hunyuan_video_end_to_end(capsys, no_cache_dir):
+    """``--arch`` picks the denoiser by configuration name: HunyuanVideo's
+    MMDiT, with pytree conditioning and embedded guidance, through the
+    same engine."""
+    from repro.launch import serve
+
+    serve.main(["--arch", "hunyuanvideo-dit", "--reduced", "--latent",
+                "4x8x12", "--requests", "2", "--steps", "2",
+                "--partitions", "2", "--max-batch", "2"])
+    out = capsys.readouterr().out
+    assert "config: hunyuanvideo-dit-reduced blocks=3 d=128" in out
+    lines = [l for l in out.splitlines() if l.startswith("request ")]
+    assert len(lines) == 2
+    assert all("latent (1, 4, 8, 12, 4)" in l and "batch=2" in l
+               for l in lines)
+
+
 def test_loadtest_reduced_end_to_end(no_cache_dir):
     from repro.launch import loadtest
 

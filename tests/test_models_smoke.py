@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from repro import models
-from repro.configs import ALL_ARCHS, get_config
+from repro.configs import ALL_ARCHS, VDM_ARCHS, get_config
 from repro.models import frontends
 
 B, S = 2, 24
@@ -28,7 +28,8 @@ def _batch(key, cfg):
         batch = {
             "latent": jax.random.normal(kz, (B, 4, 8, 8, cfg.latent_channels)),
             "t": jnp.full((B,), 500.0),
-            "context": frontends.text_context(kc, B, cfg),
+            "context": frontends.conditioning(kc, B, cfg),
+            "guidance": jnp.full((B,), 5.0),
         }
     return batch
 
@@ -48,7 +49,7 @@ def test_forward_smoke(arch):
     assert not np.isnan(np.asarray(out, np.float32)).any(), f"{arch}: NaNs"
 
 
-@pytest.mark.parametrize("arch", [a for a in ALL_ARCHS if a != "wan21-dit-1.3b"])
+@pytest.mark.parametrize("arch", [a for a in ALL_ARCHS if a not in VDM_ARCHS])
 def test_train_step_smoke(arch):
     """One loss+grad step: finite loss, finite grads, params update."""
     cfg = get_config(arch).reduced()
@@ -73,7 +74,7 @@ def test_train_step_smoke(arch):
 
 @pytest.mark.parametrize(
     "arch",
-    [a for a in ALL_ARCHS if a not in ("wan21-dit-1.3b", "whisper-small")],
+    [a for a in ALL_ARCHS if a not in (*VDM_ARCHS, "whisper-small")],
 )
 def test_decode_step_smoke(arch):
     cfg = get_config(arch).reduced()

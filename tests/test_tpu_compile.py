@@ -180,3 +180,61 @@ def test_smoke_guided_step_fits_v5e(one_chip, dim, monkeypatch):
     assert total < V5E_USABLE_HBM, (mem.temp_size_in_bytes,
                                     mem.argument_size_in_bytes)
     assert mem.temp_size_in_bytes < SMOKE_STEP_TEMP, mem.temp_size_in_bytes
+
+
+# temporaries of HunyuanVideo's quarter-depth K=2 step at 9x68x120
+# (33 frames at 544x960): 3.67 / 4.01 / 3.67 GB (T/H/W) on top of
+# 6.96 GB of parameters, 10.97 GB at most of the chip's 15.75
+HYV_STEP_TEMP = 4.5e9
+
+
+def test_hunyuan_video_step_fits_v5e(one_chip, monkeypatch):
+    """The one-chip engine's compiled LP step for HunyuanVideo's MMDiT at
+    published widths and a quarter of its depth (5 double + 10 single
+    blocks, the benchmark's configuration): K=2 windows of the 33-frame
+    544x960 latent on the H rotation (the largest temporaries), one
+    forward per window with the guidance embedded, joint attention over
+    18,076 tokens in the DiT flash kernel."""
+    import sys
+    from pathlib import Path
+
+    from repro.kernels import ops
+    from repro.models import hunyuan_video
+    from repro.obs.scopes import scope_map
+    from repro.serving.engine import LPServingEngine
+
+    bench = Path(__file__).resolve().parents[1] / "bench"
+    if str(bench) not in sys.path:
+        sys.path.insert(0, str(bench))
+    from benchlib import spec
+
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    conf = spec.config("hunyuanvideo-544p-33f", bench)
+    forward, cfg = spec.model(conf["model"], bench).program(conf)
+    engine = LPServingEngine(forward, None, cfg, num_partitions=2,
+                             num_steps=3, max_batch=1)
+    comp = engine._compiler
+    comp.use_kernel = True
+    params = jax.eval_shape(lambda k: hunyuan_video.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    params = jax.tree.map(
+        lambda x: _sds(x.shape, x.dtype, one_chip), params)
+    cond = {"text": _sds((1, 256, 4096), jnp.float32, one_chip),
+            "pooled": _sds((1, 768), jnp.float32, one_chip)}
+    scalar = _sds((), jnp.float32, one_chip)
+    extras = (params, cond, scalar)
+    z = _sds((1, *conf["latent"], cfg.latent_channels), jnp.float32,
+             one_chip)
+    step = comp.step_fn(1, z, 1, np.float32(0.0), extras)
+    compiled = step.lower(z, scalar, scalar, extras).compile()
+    _assert_kernel(compiled)
+    scopes = scope_map(compiled.as_text())
+    assert {scope for name, scope in scopes.items()
+            if name.startswith("dit_flash_attention")} == {
+        "mmdit.refiner", "mmdit.double_attn", "mmdit.single"}
+    mem = compiled.memory_analysis()
+    total = mem.temp_size_in_bytes + mem.argument_size_in_bytes
+    assert mem.argument_size_in_bytes > 6.9e9   # the parameters are args
+    assert total < V5E_USABLE_HBM, (mem.temp_size_in_bytes,
+                                    mem.argument_size_in_bytes)
+    assert mem.temp_size_in_bytes < HYV_STEP_TEMP, mem.temp_size_in_bytes
