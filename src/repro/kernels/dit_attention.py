@@ -1,11 +1,13 @@
-"""Pallas TPU kernel: bidirectional (DiT) flash attention, bf16 MXU operands.
+"""Pallas TPU kernels: bidirectional (DiT) flash attention with bf16 MXU
+operands, and the RoPE pass that feeds it.
 
 The DiT's self-attention (every video token sees every other) and its
 cross-attention (video tokens over the text context) are one operation:
-softmax(q k^T / sqrt(D)) v with no mask.  This kernel runs it as one
-online-softmax sweep per (row, head, q block):
+softmax(q k^T / sqrt(D)) v with no mask.  ``dit_attention`` takes q, k
+and v as the projections produce them, plus optional RoPE tables, and
+runs it as one online-softmax sweep per (row, head, q block):
 
-    q block   (bq, D)     bf16, scaled by 1/sqrt(D) before the kernel
+    q block   (bq, D)     bf16, scaled by 1/sqrt(D) before the sweep
     k/v block (bkv, D)    bf16, swept in bkv_compute-wide chunks
     m, l      (bq, 128)   f32 scratch, lane-replicated running max / sum
     acc       (bq, D)     f32 scratch
@@ -20,16 +22,28 @@ kernel.  A head size that is not a multiple of the 128 lanes is
 zero-padded to one (zero columns change no score, and the padded output
 columns are dropped).
 
-Lengths need not be block multiples (7,800 tokens at 17 frames): q and
-kv are zero-padded to their block multiples, padded query rows are
-dropped, and padded keys are masked by a static compare in the last kv
-block only, whose chunks that hold no real key are skipped outright.
-Every other block runs unmasked.
+Ragged tails are read in place: lengths need not be block multiples
+(7,800 tokens at 17 frames), and nothing pads the sequences.  The grid
+runs over ``cdiv`` of each length; the last q block is partial and its
+writes past Sq are discarded.  Only the last kv block masks the keys
+past Skv, by a static compare, and zeroes the values there (a partial
+block's rows past the array hold unspecified bits, and 0 * NaN is NaN);
+its chunks that hold no key are skipped outright.  Every other block
+runs unmasked.
+
+RoPE (``rope = (cos, sin)``, each (S, D) f32 in rotate-half form: cos
+over both halves, sin as [-sin, +sin]): ``dit_rope`` rotates q and k in
+one pass each, ``x * cos + swap_halves(x) * sin`` with the halves
+swapped by a lane roll, bf16 in, f32 math, bf16 out.  q's pass folds
+the 1/sqrt(D) scale into its tables, so it replaces the scale pass.  The
+flash kernel does not rotate k itself: it would fetch k's tables again
+for every q block.
 """
 from __future__ import annotations
 
 import functools
 import math
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -63,23 +77,27 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         sl = pl.ds(c * bkv_compute, bkv_compute)
         s = jax.lax.dot_general(q_ref[...], k_ref[sl, :], NT,
                                 preferred_element_type=jnp.float32)
+        v = v_ref[sl, :]
         if valid < bkv_compute:
             col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
             s = jnp.where(col < valid, s, NEG_INF)
+            # the rows past the array hold unspecified bits: 0 * NaN is NaN
+            row = jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+            v = jnp.where(row < valid, v, jnp.zeros_like(v))
         m_prev, l_prev = m_ref[...], l_ref[...]
         m_next = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - _lanes(m_next, bkv_compute))
         alpha = jnp.exp(m_prev - m_next)
         l_ref[...] = alpha * l_prev + p.sum(axis=-1, keepdims=True)
         m_ref[...] = m_next
-        pv = jax.lax.dot_general(p.astype(v_ref.dtype), v_ref[sl, :], NN,
+        pv = jax.lax.dot_general(p.astype(v.dtype), v, NN,
                                  preferred_element_type=jnp.float32)
         acc_ref[...] = _lanes(alpha, acc_ref.shape[-1]) * acc_ref[...] + pv
 
     def sweep(valid_rows):
         for c in range(bkv // bkv_compute):
             valid = valid_rows - c * bkv_compute
-            if valid > 0:                 # a chunk of padding only: skip
+            if valid > 0:                 # a chunk past the keys: skip
                 chunk(c, min(valid, bkv_compute))
 
     if tail == bkv:
@@ -104,6 +122,49 @@ def _pad_to(x, axis, multiple):
     return jnp.pad(x, widths)
 
 
+def _pad_halves(x, width):
+    """Zero-pad each half of the last axis to ``width // 2``, so that a
+    rotate-half pair stays ``width // 2`` lanes apart."""
+    half = x.shape[-1] // 2
+    if 2 * half == width:
+        return x
+    return jnp.concatenate([_pad_to(h, x.ndim - 1, width // 2)
+                            for h in jnp.split(x, 2, axis=-1)], axis=-1)
+
+
+def _rope_kernel(x_ref, cos_ref, sin_ref, o_ref, *, scale: float):
+    x = x_ref[...].astype(jnp.float32)
+    cos, sin = cos_ref[...], sin_ref[...]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
+    swapped = pltpu.roll(x, x.shape[-1] // 2, 1)      # [x2, x1]
+    o_ref[...] = (x * cos + swapped * sin).astype(o_ref.dtype)
+
+
+def dit_rope(x, cos, sin, *, scale: float = 1.0, block_s: int = 1024,
+             interpret: bool = False):
+    """``scale * (x * cos + swap_halves(x) * sin)`` for each head of
+    ``x`` (B, S, H*Dp), with (S, Dp) f32 rotate-half tables: one pass,
+    the math in f32, the result in x's dtype."""
+    B, S, W = x.shape
+    Dp = cos.shape[-1]
+    bs = min(block_s, S + (-S % 16))
+    table = pl.BlockSpec((bs, Dp), lambda i, b, h: (i, 0))
+    head = pl.BlockSpec((None, bs, Dp), lambda i, b, h: (b, i, h))
+    return pl.pallas_call(
+        functools.partial(_rope_kernel, scale=scale),
+        # heads innermost: a table block is fetched once per row block
+        grid=(pl.cdiv(S, bs), B, W // Dp),
+        in_specs=[head, table, table],
+        out_specs=head,
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel")),
+        interpret=interpret,
+        name="dit_rope",
+    )(x, cos, sin)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("block_q", "block_kv", "block_kv_compute", "interpret"),
@@ -112,28 +173,36 @@ def dit_attention(
     q: jnp.ndarray,            # (B, Sq, H, D)
     k: jnp.ndarray,            # (B, Skv, H, D)
     v: jnp.ndarray,            # (B, Skv, H, D)
+    rope: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,  # (S, D) f32 x2
     block_q: int = 1024,
     block_kv: int = 2048,
     block_kv_compute: int = 512,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Unmasked softmax(q k^T / sqrt(D)) v, (B, Sq, H, D), for q, k and v
-    of one dtype (bf16 on the DiT's path)."""
+    of one dtype (bf16 on the DiT's path); with ``rope``, q and k are
+    first rotated by its rotate-half tables (self-attention, Sq = Skv)."""
     B, Sq, H, D = q.shape
     Skv = k.shape[1]
     dt = q.dtype
     scale = 1.0 / math.sqrt(D)
-    q = (q.astype(jnp.float32) * scale).astype(dt)
     Dp = D + (-D % LANES)
     # blocks no longer than the (lane-rounded) sequences they tile
     bq = min(block_q, Sq + (-Sq % LANES))
     bkv = min(block_kv, Skv + (-Skv % LANES))
     bkc = math.gcd(min(block_kv_compute, bkv), bkv)
-    q, k, v = (_pad_to(x, 3, LANES) for x in (q, k, v))
-    q = _pad_to(q, 1, bq).reshape(B, -1, H * Dp)
-    k = _pad_to(k, 1, bkv).reshape(B, -1, H * Dp)
-    v = _pad_to(v, 1, bkv).reshape(B, -1, H * Dp)
-    nq, nk = q.shape[1] // bq, k.shape[1] // bkv
+    if rope is None:
+        q = (q.astype(jnp.float32) * scale).astype(dt)
+        q, k = (_pad_to(x, 3, LANES) for x in (q, k))
+    else:
+        q, k = (_pad_halves(x, Dp) for x in (q, k))
+        cos, sin = (_pad_halves(t.astype(jnp.float32), Dp) for t in rope)
+    q, k, v = (x.reshape(B, x.shape[1], H * Dp)
+               for x in (q, k, _pad_to(v, 3, LANES)))
+    if rope is not None:
+        q = dit_rope(q, cos, sin, scale=scale, interpret=interpret)
+        k = dit_rope(k, cos, sin, interpret=interpret)
+    nq, nk = pl.cdiv(Sq, bq), pl.cdiv(Skv, bkv)
     kernel = functools.partial(
         _kernel, bkv=bkv, bkv_compute=bkc, num_kv_blocks=nk,
         tail=Skv - (nk - 1) * bkv)
@@ -158,4 +227,5 @@ def dit_attention(
         interpret=interpret,
         name="dit_flash_attention",
     )(q, k, v)
-    return out.reshape(B, -1, H, Dp)[:, :Sq, :, :D]
+    out = out.reshape(B, Sq, H, Dp)
+    return out if Dp == D else out[..., :D]

@@ -45,14 +45,16 @@ def flash_attention(q, k, v, q_positions, kv_positions, *, causal=True,
                   interpret=_interpret(interpret), skip_upper=skip_upper)
 
 
-def dit_attention(q, k, v, *, interpret=None):
+def dit_attention(q, k, v, *, rope=None, interpret=None):
     """Unmasked (DiT) attention, (B, Sq, H, D) -> (B, Sq, H, D): the
-    fused bf16 flash kernel.  Blocks from a v5e sweep at 7.8k-18.7k
-    tokens: kv blocks of 2048 in 512-row chunks, q blocks of 1024 rows,
-    or 512 where that pads the queries less (7,540 -> 7,680, not 8,192)."""
+    fused bf16 flash kernel, with q and k first rotated by ``rope``'s
+    rotate-half (cos, sin) tables where it is given.  Blocks from a v5e
+    sweep at 7.8k-18.7k tokens: kv blocks of 2048 in 512-row chunks, q
+    blocks of 1024 rows, or 512 where that computes fewer rows past the
+    end (7,540 tokens: 7,680 rows in 15 blocks, not 8,192 in 8)."""
     S = q.shape[1]
     block_q = 512 if -S % 512 < -S % 1024 else 1024
-    return _dit_attention(q, k, v, block_q=block_q,
+    return _dit_attention(q, k, v, rope, block_q=block_q,
                           interpret=_interpret(interpret))
 
 

@@ -109,8 +109,10 @@ def _unpatchify(tok: jnp.ndarray, grid, cfg: ArchConfig, out_shape):
     return z.reshape(out_shape)
 
 
-def _axial_rope(q, grid, origin, head_dim, theta=10_000.0):
-    """3D axial RoPE over (t, h, w) patch coordinates (global coords)."""
+def rope_tables(grid, origin, head_dim, theta=10_000.0):
+    """3D axial RoPE over (t, h, w) patch coordinates (global coords), as
+    rotate-half tables ``(cos, sin)``, each (N_tokens, head_dim) f32: cos
+    over both halves, sin as [-sin, +sin] (:func:`apply_rope`)."""
     from .layers import rope_frequencies
 
     nt, nh, nw = grid
@@ -133,17 +135,26 @@ def _axial_rope(q, grid, origin, head_dim, theta=10_000.0):
         a = a.reshape(shape)
         a = jnp.broadcast_to(a, (nt, nh, nw, dd // 2))
         angles.append(a)
-    ang = jnp.concatenate(angles, axis=-1).reshape(1, nt * nh * nw, 1, head_dim // 2)
+    ang = jnp.concatenate(angles, axis=-1).reshape(nt * nh * nw, head_dim // 2)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = jnp.split(q.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-    return out.astype(q.dtype)
+    return (jnp.concatenate([cos, cos], axis=-1),
+            jnp.concatenate([-sin, sin], axis=-1))
 
 
-def _attn(params, x, cfg, grid=None, origin=(0, 0, 0), context=None,
-          kv_chunk: int = 4096):
+def apply_rope(x, rope):
+    """``x * cos + swap_halves(x) * sin`` for x (B, S, H, D) in f32, back
+    in x's dtype: what the kernel's ``dit_rope`` pass computes."""
+    cos, sin = (t[:, None, :] for t in rope)
+    xf = x.astype(jnp.float32)
+    x1, x2 = jnp.split(xf, 2, axis=-1)
+    return (xf * cos + jnp.concatenate([x2, x1], axis=-1) * sin
+            ).astype(x.dtype)
+
+
+def _attn(params, x, cfg, rope=None, context=None, kv_chunk: int = 4096):
     """Bidirectional (DiT) self- or cross-attention, through
-    :func:`attend`."""
+    :func:`attend`; ``rope`` (:func:`rope_tables`) rotates self-attention's
+    q and k."""
     B, S, _ = x.shape
     H, D = cfg.num_heads, cfg.head_dim
     src = x if context is None else context
@@ -151,32 +162,33 @@ def _attn(params, x, cfg, grid=None, origin=(0, 0, 0), context=None,
     q = dense(params["q"], x).reshape(B, S, H, D)
     k = dense(params["k"], src).reshape(B, Skv, H, D)
     v = dense(params["v"], src).reshape(B, Skv, H, D)
-    if context is None and grid is not None:
-        q = _axial_rope(q, grid, origin, D)
-        k = _axial_rope(k, grid, origin, D)
     # sequence-parallel attention inside LP windows: 12 heads don't divide
     # a 16-way TP axis, so shard query tokens instead (§Perf C)
     q = actctx.shard_attn_q(q)
     k = actctx.shard_attn_kv(k)
     v = actctx.shard_attn_kv(v)
-    out = attend(q, k, v, kv_chunk)
+    out = attend(q, k, v, kv_chunk, rope=rope)
     out = actctx.shard_attn_out(out.reshape(B, S, H * D))
     return dense(params["o"], out)
 
 
-def attend(q, k, v, kv_chunk: int = 4096):
-    """Unmasked attention, (B, S, H, D) x (B, Skv, H, D): the fused
-    kernel ``kernels.ops.dit_attention`` where the backend compiles
-    Pallas and no GSPMD activation context is active (a ``pallas_call``
-    cannot be auto-partitioned), else the chunked online-softmax scan."""
+def attend(q, k, v, kv_chunk: int = 4096, rope=None):
+    """Unmasked attention, (B, S, H, D) x (B, Skv, H, D), q and k first
+    rotated by ``rope``'s tables where given: the fused kernel
+    ``kernels.ops.dit_attention`` where the backend compiles Pallas and
+    no GSPMD activation context is active (a ``pallas_call`` cannot be
+    auto-partitioned), else :func:`apply_rope` and the chunked
+    online-softmax scan."""
     if kernel_ops.default_interpret() or actctx.active():
+        if rope is not None:
+            q, k = apply_rope(q, rope), apply_rope(k, rope)
         B, S = q.shape[:2]
         Skv = k.shape[1]
         qp = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
         kp = jnp.broadcast_to(jnp.arange(Skv)[None], (B, Skv))
         return attention_chunked(q, k, v, qp, kp, causal=False,
                                  kv_chunk=kv_chunk)
-    return kernel_ops.dit_attention(q, k, v)
+    return kernel_ops.dit_attention(q, k, v, rope=rope)
 
 
 def _modulate(x, shift, scale):
@@ -210,6 +222,7 @@ def forward(
         temb = dense(params["time_mlp"]["w2"],
                      jax.nn.silu(dense(params["time_mlp"]["w1"], temb)))
         temb = jax.nn.silu(temb)                               # (B, time_dim)
+        rope = rope_tables(grid, origin, cfg.head_dim)
 
     def body(h, blk):
         with jax.named_scope("dit.adaln"):
@@ -221,7 +234,7 @@ def forward(
                            b1, s1)
         with jax.named_scope("dit.self_attn"):
             h = h + g1[:, None, :] * _attn(
-                blk["self_attn"], hn, cfg, grid, origin, kv_chunk=kv_chunk
+                blk["self_attn"], hn, cfg, rope, kv_chunk=kv_chunk
             )
         with jax.named_scope("dit.cross_attn"):
             h = h + _attn(

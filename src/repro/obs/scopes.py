@@ -17,9 +17,9 @@ from typing import Dict, List
 # innermost wins: a DiT sub-block inside the LP window's vmap reads as
 # the sub-block, the loop machinery around the blocks as ``dit.blocks``
 SCOPES = (
-    "dit.embed",        # patchify, patch/text projections, time MLP
+    "dit.embed",        # patchify, projections, time MLP, RoPE tables
     "dit.adaln",        # modulation vectors, the norms, _modulate
-    "dit.self_attn",    # q/k/v/o, RoPE, the DiT flash kernel, gated residual
+    "dit.self_attn",    # q/k/v/o, dit_rope, the flash kernel, gated residual
     "dit.cross_attn",   # cross-attention sub-block with its pre-norm
     "dit.ffn",          # the FFN and its gated residual
     "dit.blocks",       # the scan over blocks: per-block weight slices

@@ -189,23 +189,71 @@ def test_latent_blend_bit_identical_to_oracle(K, extent, patch, r, F, blk_f):
 
 
 # ----------------------------------------------------------- DiT attention
-# (rows, B, Sq, Skv, H, D, blocks): ``blocks`` None takes the kernel's own
-# (v5e-swept) sizes through ``ops``; rows > 0 vmaps a leading axis the way
-# the one-chip engine maps LP windows over the step
-@pytest.mark.parametrize("rows,B,Sq,Skv,H,D,blocks", [
-    (0, 2, 300, 300, 2, 128, (128, 256, 128)),    # padded tail, 1 kv block
-    (0, 1, 1000, 1000, 2, 128, (256, 256, 128)),  # tail chunk masked, one skipped
-    (0, 1, 300, 512, 2, 128, (128, 1024, 512)),   # cross-attention: S x 512
-    (0, 1, 260, 260, 12, 128, (128, 128, 128)),   # WAN2.1's 12 heads of 128
-    (2, 2, 300, 300, 2, 128, (128, 256, 128)),    # vmapped rows
-    (0, 1, 200, 260, 3, 32, None),                # head size padded to 128
-], ids=["self300", "self1000", "cross512", "h12d128", "vmap_rows", "d32"])
-def test_dit_attention(rows, B, Sq, Skv, H, D, blocks):
+def _rotate_f32(x, grid, origin):
+    """x (B, S, H, D) rotated in f32 by the axial angles of ``grid`` at
+    ``origin``: (x1 cos - x2 sin, x2 cos + x1 sin) over the two halves."""
+    from repro.models import dit
+
+    cos, sin = (t[:, None, :] for t in dit.rope_tables(grid, origin,
+                                                       x.shape[-1]))
+    half = x.shape[-1] // 2
+    c, s = cos[..., :half], sin[..., half:]
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
+
+
+def test_rope_tables_are_the_axial_angles():
+    """``dit.rope_tables`` in rotate-half form: token (t, h, w) at origin
+    (ot, oh, ow) turns pair i of the t part (the first
+    ``(D // 3) & ~1`` dims' halves) by (t + ot) theta^(-2i/d_t), then the
+    h part, then w; cos over both halves, sin as [-sin, +sin]."""
+    from repro.models import dit
+
+    grid, origin, D = (2, 3, 4), (1, 0, 2), 32
+    cos, sin = (np.asarray(t) for t in dit.rope_tables(grid, origin, D))
+    d_t = d_h = (D // 3) & ~1
+    parts = [(g, o, d) for g, o, d in zip(grid, origin,
+                                          (d_t, d_h, D - d_t - d_h))]
+    t, h, w = np.meshgrid(*[np.arange(g) + o for g, o, _ in parts],
+                          indexing="ij")
+    ang = np.concatenate(
+        [pos.reshape(-1, 1) * 10_000.0 ** (-np.arange(0, d, 2) / d)
+         for pos, (_, _, d) in zip((t, h, w), parts)], axis=1)
+    assert cos.shape == sin.shape == (np.prod(grid), D)
+    np.testing.assert_allclose(cos, np.cos(np.tile(ang, 2)), atol=1e-6)
+    np.testing.assert_allclose(
+        sin, np.concatenate([-np.sin(ang), np.sin(ang)], 1), atol=1e-6)
+
+
+# (rows, B, Sq, Skv, H, D, blocks, rope): ``blocks`` None takes the
+# kernel's own (v5e-swept) sizes through ``ops``; rows > 0 vmaps a leading
+# axis the way the one-chip engine maps LP windows over the step; ``rope``
+# (grid, one origin per row) passes the tables of ``dit.rope_tables``
+@pytest.mark.parametrize("rows,B,Sq,Skv,H,D,blocks,rope", [
+    (0, 2, 300, 300, 2, 128, (128, 256, 128), None),    # ragged tails
+    (0, 1, 1000, 1000, 2, 128, (256, 256, 128), None),  # a tail chunk skipped
+    (0, 1, 300, 512, 2, 128, (128, 1024, 512), None),   # cross: S x 512
+    (0, 1, 260, 260, 12, 128, (128, 128, 128), None),   # WAN's 12 x 128
+    (2, 2, 300, 300, 2, 128, (128, 256, 128), None),    # vmapped rows
+    (0, 1, 200, 260, 3, 32, None, None),                # head padded to 128
+    (0, 2, 300, 300, 2, 128, (128, 256, 128),
+     ((3, 10, 10), [(0, 0, 0)])),
+    (0, 1, 1000, 1000, 2, 128, (256, 256, 128),
+     ((10, 10, 10), [(0, 0, 0)])),
+    (2, 2, 300, 300, 2, 128, (128, 256, 128),
+     ((3, 10, 10), [(0, 0, 0), (2, 5, 4)])),            # LP windows' origins
+    (0, 1, 260, 260, 3, 32, None, ((1, 13, 20), [(0, 0, 0)])),
+], ids=["self300", "self1000", "cross512", "h12d128", "vmap_rows", "d32",
+        "self300_rope", "self1000_rope", "vmap_origins_rope", "d32_rope"])
+def test_dit_attention(rows, B, Sq, Skv, H, D, blocks, rope):
     """The bf16 DiT kernel against f32 dense attention on the same bf16
-    inputs.  Tolerance: three bf16 roundings (the 1/sqrt(D)-scaled q, the
-    probabilities as the PV operand, the output), each at most the unit
-    roundoff 2^-9 relative, on an output that is a convex combination of
-    the values; so the error stays under 3 * 2^-9 * max|v|."""
+    inputs, with q and k rotated in f32 where ``rope`` is given.
+    Tolerance: three bf16 roundings (the 1/sqrt(D)-scaled q, rotated
+    first where RoPE applies, the probabilities as the PV operand, the
+    output), each at most the unit roundoff 2^-9 relative, on an output
+    that is a convex combination of the values; so the error stays under
+    3 * 2^-9 * max|v|.  The rotated k's one rounding moves the scores
+    as the scaled q's does, and the same bound holds for it."""
     lead = (rows,) if rows else ()
     rng = np.random.default_rng(Sq + Skv + H)
     q, k, v = (jnp.asarray(rng.normal(size=lead + (B, S, H, D)), jnp.bfloat16)
@@ -216,9 +264,25 @@ def test_dit_attention(rows, B, Sq, Skv, H, D, blocks):
         bq, bkv, bkc = blocks
         fn = functools.partial(dit_attention, block_q=bq, block_kv=bkv,
                                block_kv_compute=bkc, interpret=True)
-    out = jax.vmap(fn)(q, k, v) if rows else fn(q, k, v)
-    f32 = [x.reshape((-1,) + x.shape[-3:]).astype(jnp.float32)
-           for x in (q, k, v)]
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    if rope is None:
+        out = jax.vmap(fn)(q, k, v) if rows else fn(q, k, v)
+    else:
+        from repro.models import dit
+
+        grid, origins = rope
+        tables = [dit.rope_tables(grid, o, D) for o in origins]
+        if rows:
+            cos, sin = (jnp.stack(t) for t in zip(*tables))
+            out = jax.vmap(lambda q, k, v, c, s: fn(q, k, v, rope=(c, s)))(
+                q, k, v, cos, sin)
+            f32[:2] = [jnp.stack([_rotate_f32(x[r], grid, o)
+                                  for r, o in enumerate(origins)])
+                       for x in f32[:2]]
+        else:
+            out = fn(q, k, v, rope=tables[0])
+            f32[:2] = [_rotate_f32(x, grid, origins[0]) for x in f32[:2]]
+    f32 = [x.reshape((-1,) + x.shape[-3:]) for x in f32]
     n = f32[0].shape[0]
     want = attention_dense(*f32, jnp.zeros((n, Sq), jnp.int32),
                            jnp.zeros((n, Skv), jnp.int32), causal=False)
@@ -249,10 +313,49 @@ def test_dit_attention_path(monkeypatch, tpu, sharded, kernel):
     blk = jax.tree.map(lambda x: x[0], params["blocks"])["self_attn"]
     x = jnp.zeros((1, 16, cfg.d_model), jnp.dtype(cfg.dtype))
     ctx = actctx.batch_axes(("data",)) if sharded else contextlib.nullcontext()
+    rope = dit.rope_tables((1, 4, 4), (0, 0, 0), cfg.head_dim)
     with ctx:
         jaxpr = jax.make_jaxpr(
-            lambda x: dit._attn(blk, x, cfg, grid=(1, 4, 4)))(x)
+            lambda x: dit._attn(blk, x, cfg, rope))(x)
     assert ("pallas_call" in str(jaxpr)) == kernel
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_dit_forward_kernel_path_matches_scan(monkeypatch, dtype):
+    """``dit.forward`` at a reduced config predicts the same on both
+    attention paths: the kernel (interpret mode) with RoPE from the
+    tables in its ``dit_rope`` pass and the scale folded into q's, and
+    the chunked scan after :func:`dit.apply_rope` in jnp.  A sign or a
+    half swapped in either RoPE would move the prediction by O(1); the
+    bf16 tolerance (a few units of 2^-8 of the output's scale) admits
+    only rounding, so the two apply one formula."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models import dit
+
+    cfg = dataclasses.replace(get_config("wan21-dit-1.3b").reduced(),
+                              dtype=dtype)
+    params = dit.init_params(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(3)
+    z = jnp.asarray(rng.normal(size=(2, 3, 8, 12, cfg.latent_channels)),
+                    jnp.float32)
+    t = jnp.asarray([700.0, 20.0])
+    ctx = jnp.asarray(rng.normal(size=(2, cfg.context_len, cfg.context_dim)),
+                      jnp.float32)
+
+    def predict(kernel):
+        monkeypatch.setattr(ops, "default_interpret", lambda: not kernel)
+        return jax.jit(lambda z: dit.forward(params, z, t, ctx, cfg,
+                                             origin=(1, 2, 4)))(z)
+
+    kernel = functools.partial(ops.dit_attention, interpret=True)
+    monkeypatch.setattr(ops, "dit_attention", kernel)
+    got, want = (np.asarray(predict(k), np.float32) for k in (True, False))
+    monkeypatch.undo()
+    scale = float(np.abs(want).max())
+    assert scale > 0.1
+    np.testing.assert_allclose(got, want, atol=4 * 2.0 ** -8 * scale, rtol=0)
 
 
 def test_kernels_interpret_only_off_tpu():

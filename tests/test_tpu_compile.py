@@ -11,6 +11,7 @@ one process may hold the TPU library at a time, and every test worker
 imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -77,6 +78,12 @@ def _window(latent_k, dim, batch=1):
     return plan, rest * CHANNELS * batch
 
 
+# an HLO instruction line: (name, result shape, opcode)
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*?)\s([\w\-]+)\(", re.M)
+_F32_HEADS = re.compile(r"f32\[[\d,]*,12,128\]")
+
+
 def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
@@ -120,21 +127,33 @@ def test_int8_quantize_compiles_for_v5e(one_chip, latent_k, dim, batch):
         _sds((plan.window, F), jnp.float32, one_chip)).compile())
 
 
-@pytest.mark.parametrize("rows,sq,skv", [
-    (2, 7800, 7800), (2, 7540, 7540), (2, 7800, 512),   # one chip: K=2
-    (1, 18720, 18720), (1, 17472, 17472), (1, 15750, 15750),  # a K=4 window
-], ids=["17f_TW", "17f_H", "17f_cross", "81f_T", "81f_H", "81f_W"])
-def test_dit_attention_compiles_for_v5e(one_chip, rows, sq, skv):
+@pytest.mark.parametrize("rows,sq,skv,rope", [
+    (2, 7800, 7800, False), (2, 7540, 7540, False),    # one chip: K=2
+    (2, 7800, 512, False),
+    (1, 18720, 18720, False), (1, 17472, 17472, False),  # a K=4 window
+    (1, 15750, 15750, False),
+    (2, 7540, 7540, True), (1, 18720, 18720, True), (1, 15750, 15750, True),
+], ids=["17f_TW", "17f_H", "17f_cross", "81f_T", "81f_H", "81f_W",
+        "17f_H_rope", "81f_T_rope", "81f_W_rope"])
+def test_dit_attention_compiles_for_v5e(one_chip, rows, sq, skv, rope):
     """The DiT flash kernel at WAN2.1-1.3B's 12 heads of 128 on the
     cells' window lengths (the CFG pair is the batch, LP windows a
-    vmapped axis), with the block sizes ``ops`` picks."""
+    vmapped axis), with the block sizes ``ops`` picks; with ``rope``,
+    the ``dit_rope`` passes in front of it on one table pair for all
+    windows, as the engine's step has it."""
     from repro.kernels import ops
 
-    fn = jax.jit(jax.vmap(lambda q, k, v: ops.dit_attention(
-        q, k, v, interpret=False)))
     q = _sds((rows, 2, sq, 12, 128), jnp.bfloat16, one_chip)
     kv = _sds((rows, 2, skv, 12, 128), jnp.bfloat16, one_chip)
-    _assert_kernel(fn.lower(q, kv, kv).compile())
+    args = (q, kv, kv)
+    if rope:
+        args += (_sds((sq, 128), jnp.float32, one_chip),) * 2
+    fn = jax.jit(lambda q, k, v, *tables: jax.vmap(
+        lambda q, k, v: ops.dit_attention(
+            q, k, v, rope=tables or None, interpret=False))(q, k, v))
+    text = fn.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert ("dit_rope" in text) == rope
 
 
 @pytest.mark.parametrize("dim", [0, 1, 2])
@@ -170,10 +189,19 @@ def test_smoke_guided_step_fits_v5e(one_chip, dim, monkeypatch):
     step = comp.step_fn(dim, z, 1, np.float32(0.0), extras)
     compiled = step.lower(z, scalar, scalar, extras).compile()
     _assert_kernel(compiled)
-    scopes = scope_map(compiled.as_text())
+    text = compiled.as_text()
+    scopes = scope_map(text)
     assert {scope for name, scope in scopes.items()
             if name.startswith("dit_flash_attention")} == {
         "dit.self_attn", "dit.cross_attn"}
+    assert {scope for name, scope in scopes.items()
+            if name.startswith("dit_rope")} == {"dit.self_attn"}
+    # the kernels take the projections as they are: no padding, slicing
+    # or f32 copy of q/k/v (B, S, 12 heads, 128) around either attention
+    glue = [(name, shape, op) for name, shape, op in _INSTRUCTION.findall(text)
+            if scopes.get(name) in ("dit.self_attn", "dit.cross_attn")
+            and (op in ("pad", "slice") or _F32_HEADS.search(shape))]
+    assert not glue, glue[:8]
     mem = compiled.memory_analysis()
     total = mem.temp_size_in_bytes + mem.argument_size_in_bytes
     assert mem.argument_size_in_bytes > 4.4e9   # the parameters are args
